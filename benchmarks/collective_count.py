@@ -92,7 +92,9 @@ def main():
          f"ok={worst == 1 and extra == 0}")
 
     # cross-check against the compiled 8-device artifacts.
-    env = dict(os.environ, PYTHONPATH=SRC)
+    # forced host devices: the child never claims an accelerator that
+    # this process may hold.
+    env = dict(os.environ, PYTHONPATH=SRC, JAX_PLATFORMS="cpu")
     out = subprocess.run([sys.executable, "-c", CODE], env=env,
                          capture_output=True, text=True, timeout=1800)
     if out.returncode != 0:

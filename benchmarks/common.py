@@ -19,13 +19,10 @@ def header():
 
 
 def timeit(fn, *args, repeats: int = 3):
-    fn(*args)                       # warmup / compile
+    import jax
+    jax.block_until_ready(fn(*args))    # warmup / compile
     t0 = time.perf_counter()
     for _ in range(repeats):
         out = fn(*args)
-    try:
-        import jax
-        jax.block_until_ready(out)
-    except Exception:
-        pass
+    jax.block_until_ready(out)
     return (time.perf_counter() - t0) / repeats * 1e6, out

@@ -82,7 +82,9 @@ def table3_relative_error():
         "rel = abs(float(r1.objective[-1]) - float(r2.objective[-1])) "
         "/ abs(float(r1.objective[-1]))\n"
         "print(f'{rel:.3e}')\n")
-    env = dict(os.environ, PYTHONPATH=os.path.join(
+    # f64 runs on the host: the child never claims an accelerator that
+    # this process may hold.
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=os.path.join(
         os.path.dirname(__file__), "..", "src"))
     out = subprocess.run([sys.executable, "-c", code], env=env,
                          capture_output=True, text=True, timeout=900)

@@ -55,8 +55,7 @@ flags = json.loads(sys.argv[1])
 from repro.core.distributed import lower_lasso_step
 from repro.core.types import SolverConfig
 from repro.launch.mesh import make_production_mesh
-from repro.roofline.analysis import collective_bytes_from_hlo, \
-    cost_analysis_dict
+from repro.roofline.analysis import collective_bytes_from_hlo
 mesh = make_production_mesh(multi_pod=flags.get("multi_pod", True))
 axes = ("pod", "data") if flags.get("multi_pod", True) else "data"
 H, s, mu = 64, flags.get("s", 16), flags.get("mu", 8)
@@ -68,7 +67,7 @@ c = lowered.compile()
 txt = c.as_text()
 coll = collective_bytes_from_hlo(txt)
 static = len(re.findall(r"= \S+ all-reduce\(", txt))
-ca = cost_analysis_dict(c)
+ca = c.cost_analysis()
 out = {"s": s, "static_allreduce": static, "trips": H // s,
        "runtime_msgs": static * (H // s),
        "coll_bytes_per_outer": coll["total"],
@@ -84,8 +83,7 @@ import json, sys, re, jax
 flags = json.loads(sys.argv[1])
 from repro.core.distributed import lower_svm_step
 from repro.core.types import SolverConfig
-from repro.roofline.analysis import collective_bytes_from_hlo, \
-    cost_analysis_dict
+from repro.roofline.analysis import collective_bytes_from_hlo
 mesh = jax.make_mesh((512,), ("model",))
 H, s, mu = 64, flags.get("s", 16), flags.get("mu", 8)
 kernel = flags.get("kernel", "linear")
@@ -98,7 +96,7 @@ c = lowered.compile()
 txt = c.as_text()
 coll = collective_bytes_from_hlo(txt)
 static = len(re.findall(r"= \S+ all-reduce\(", txt))
-ca = cost_analysis_dict(c)
+ca = c.cost_analysis()
 out = {"s": s, "mu": mu, "kernel": kernel, "static_allreduce": static,
        "trips": H // s, "runtime_msgs": static * (H // s),
        "coll_bytes_per_outer": coll["total"],
@@ -108,7 +106,7 @@ print("RESULT " + json.dumps(out))
 
 
 def run_config(code: str, flags: dict, timeout=1500):
-    env = dict(os.environ, PYTHONPATH=SRC)
+    env = dict(os.environ, PYTHONPATH=SRC, JAX_PLATFORMS="cpu")
     out = subprocess.run([sys.executable, "-c", code, json.dumps(flags)],
                          env=env, capture_output=True, text=True,
                          timeout=timeout)

@@ -17,9 +17,10 @@ For each checkpoint interval the same Lasso solve runs twice through
                                  with the interval — sparse checkpoints
                                  are cheap until a host dies.
 
-Needs >= 2 devices; when the interpreter was started with a single
-device (no XLA_FLAGS), the measurement re-execs itself in a subprocess
-with 4 forced CPU devices (the flag must be set before jax imports).
+The measurement runs in a child process on 4 forced host CPU devices
+(the flag must be set before jax imports; ``JAX_PLATFORMS=cpu`` keeps
+the child off any accelerator). The parent never imports jax, so it
+holds no chip the child could need.
 """
 from __future__ import annotations
 
@@ -121,14 +122,11 @@ def _measure(smoke: bool) -> None:
 
 
 def main(smoke: bool = False) -> None:
-    import jax
-    if len(jax.devices()) >= 2 or os.environ.get(_SUBPROC_FLAG) == "1":
+    if os.environ.get(_SUBPROC_FLAG) == "1":
         _measure(smoke)
         return
-    # jax is already initialized single-device in this process; re-exec
-    # with forced host devices (must precede jax import).
-    env = dict(os.environ, XLA_FLAGS=
-               "--xla_force_host_platform_device_count=4")
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4")
     env[_SUBPROC_FLAG] = "1"
     env["PYTHONPATH"] = os.pathsep.join(
         p for p in (os.path.join(ROOT, "src"),

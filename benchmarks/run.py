@@ -49,6 +49,8 @@ def main() -> None:
     ap.add_argument("--smoke", action="store_true",
                     help="CI-sized budgets for sections that support it")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     _register()
     names = args.only.split(",") if args.only else list(SECTIONS)
     header()

@@ -9,7 +9,7 @@ numpy float32 constant inserts a ``convert_element_type`` that narrows
 the computation — and nothing warns.
 
 This pass traces each family×variant solve with float64 inputs under
-``jax.experimental.enable_x64`` and walks the jaxpr (recursively, into
+``jax.enable_x64`` and walks the jaxpr (recursively, into
 scan/while/cond/pjit bodies) for ``convert_element_type`` equations
 whose source dtype is a WIDER float than their destination — each one
 is a place where precision is silently discarded, reported with its
@@ -71,7 +71,6 @@ def check_dtypes(fam: ProblemFamily,
     """Trace each variant's sharded solve with float64 inputs (x64
     enabled for the duration of the trace only — the process-global
     flag is untouched) and flag every silent float narrowing."""
-    from jax.experimental import enable_x64
     from repro.core import api
     import jax.numpy as jnp
     diags: List[Diagnostic] = []
@@ -85,7 +84,7 @@ def check_dtypes(fam: ProblemFamily,
         checked.append(where)
         cfg = variant_config(fam, variant, iterations=iterations,
                              dtype=jnp.float64)
-        with enable_x64():
+        with jax.enable_x64(True):
             traced = api.trace_sharded(fam, cfg, mesh, m=m, n=n,
                                        dtype=jnp.float64)
         for src, dst, line in find_float_narrowing(traced.jaxpr):

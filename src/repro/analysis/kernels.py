@@ -21,9 +21,9 @@ contracts that nothing cross-checked until now:
     produces nondeterministic output on real grids.
   * **Index-map / gather bounds** — every BlockSpec index map must land
     inside the operand's block grid for every grid point, and the
-    blocked-ELL SpMM's scalar-prefetch gather indices must address
-    inside the VMEM-resident dense operand (checked on a concrete
-    representative operand, padded slots included).
+    blocked-ELL SpMM's gather indices must address inside the
+    VMEM-resident dense operand (checked on a concrete representative
+    operand, padded slots included).
 
 Capture is by monkeypatching ``pallas_call`` on the shared
 ``jax.experimental.pallas`` module for the duration of one traced
@@ -67,9 +67,10 @@ class SpecView:
 @dataclasses.dataclass(frozen=True)
 class KernelCapture:
     """Everything one recorded ``pallas_call`` declares: the grid, the
-    per-operand views, scratch allocations, dimension semantics and the
-    scalar-prefetch operands (concrete arrays when the capture ran on
-    real inputs — the gather bounds check reads them)."""
+    per-operand views, scratch allocations, dimension semantics, the
+    scalar-prefetch operands and the blocked inputs' values (concrete
+    arrays when the capture ran on real inputs, else None — the gather
+    bounds check reads them)."""
 
     name: str
     grid: Tuple[int, ...]
@@ -78,6 +79,7 @@ class KernelCapture:
     scratch: Tuple[Tuple[Tuple[int, ...], Any], ...]
     semantics: Optional[Tuple[str, ...]] = None
     scalar_args: Tuple[Any, ...] = ()
+    input_values: Tuple[Any, ...] = ()
 
     def dim_semantics(self, dim: int) -> str:
         if self.semantics is None or dim >= len(self.semantics):
@@ -141,15 +143,19 @@ def capture_pallas_calls(fn: Callable, *args) -> List[KernelCapture]:
 
         def run(*call_args):
             scalars, blocked = call_args[:nsp], call_args[nsp:]
+
+            def concrete(args):
+                return tuple(None if isinstance(a, jax.core.Tracer) else a
+                             for a in args)
+
             out_views = _spec_views(
                 out_specs, out_leaves, "out") if out_leaves else ()
             records.append(KernelCapture(
                 name=getattr(kernel, "__name__", "kernel"), grid=grid,
                 inputs=_spec_views(in_specs, blocked, "in"),
                 outputs=out_views, scratch=scratch, semantics=sem,
-                scalar_args=tuple(
-                    None if isinstance(a, jax.core.Tracer) else a
-                    for a in scalars)))
+                scalar_args=concrete(scalars),
+                input_values=concrete(blocked)))
             return jax.tree_util.tree_map(
                 lambda sds: jnp.zeros(sds.shape, sds.dtype), out_shape)
 
@@ -306,8 +312,9 @@ def _describe_spmm():
     from repro.core.types import SparseOperand
     from repro.kernels.spmm.kernel import ell_spmm_pallas
     # a concrete representative blocked-ELL operand (deterministic
-    # banded pattern) so the scalar-prefetch gather indices are REAL
-    # padded data, not just shapes.
+    # banded pattern) so the gather indices are REAL padded data, not
+    # just shapes (evaluated eagerly, so the wrapper's padding of them
+    # stays concrete inside the capture's trace).
     C, Q, R = 32, 128, 8
     dense = np.zeros((R, C), np.float32)
     for i in range(R):
@@ -316,11 +323,14 @@ def _describe_spmm():
     op = SparseOperand.from_dense(dense, with_bcoo=False)
     vals, idx, blocks = (np.asarray(op.row_vals), np.asarray(op.row_cols),
                          np.asarray(op.row_blocks))
-    small = capture_pallas_calls(
-        lambda: ell_spmm_pallas(jnp.asarray(vals), jnp.asarray(idx),
-                                jnp.asarray(blocks),
-                                jnp.zeros((C, Q), jnp.float32),
-                                ell_block=op.ell_block))
+    def small_call():
+        with jax.ensure_compile_time_eval():
+            return ell_spmm_pallas(jnp.asarray(vals), jnp.asarray(idx),
+                                   jnp.asarray(blocks),
+                                   jnp.zeros((C, Q), jnp.float32),
+                                   ell_block=op.ell_block)
+
+    small = capture_pallas_calls(small_call)
     Rl, Kl, Cl, Ql = 512, 64, 2048, 128
     large = capture_pallas_calls(
         lambda v, i, b, d: ell_spmm_pallas(v, i, b, d, ell_block=8),
@@ -385,24 +395,24 @@ _DESCRIBERS: Dict[str, Callable[[], List[Tuple[str, Dict, List]]]] = {
 
 def _gather_bounds_diags(where: str, capture: KernelCapture
                          ) -> List[Diagnostic]:
-    """spmm scalar-prefetch gather bounds: every (padded) flat ELL
-    index must address a row of the VMEM-resident dense operand —
-    checked on the concrete representative operand's data."""
+    """spmm gather bounds: every (padded) ELL index must address a row
+    of the VMEM-resident dense operand — checked on the concrete
+    representative operand's data (the integer blocked input)."""
     diags: List[Diagnostic] = []
-    idx = capture.scalar_args[0] if capture.scalar_args else None
-    if idx is None:
-        return diags
-    # the dense right operand is the resident input (block == shape).
+    ints = [a for v, a in zip(capture.inputs, capture.input_values)
+            if a is not None and jnp.issubdtype(v.dtype, jnp.integer)]
+    # the dense right operand is the unblocked (whole-array) resident.
     dense = [v for v in capture.inputs
-             if v.block_shape == v.shape and len(v.shape) == 2]
-    if not dense:
+             if v.block_shape is None and len(v.shape) == 2]
+    if not ints or not dense:
         return diags
+    idx = ints[0]
     rows = dense[0].shape[0]
     lo, hi = int(np.min(idx)), int(np.max(idx))
     if lo < 0 or hi >= rows:
         diags.append(Diagnostic(
             "kernels", "error", where,
-            f"scalar-prefetch gather out of bounds: ELL indices span "
+            f"ELL gather out of bounds: indices span "
             f"[{lo}, {hi}] but the resident dense operand has {rows} "
             f"rows — padded slots must gather row 0 (value 0), never "
             f"past the operand"))

@@ -1,9 +1,10 @@
 """Replication-divergence taint analysis over shard_map jaxprs.
 
-The sharded solvers run with ``check_rep=False`` (jax 0.4.x's
-replication checker rejects several legitimate SA programs), which
-means NOTHING verifies the replication contract the drivers rely on:
-every output ``solve_sharded`` declares replicated (``out_specs=P()``)
+The sharded solvers run with ``check_vma=False`` (jax's own check
+rejects the kernel-SVM and logistic-regression programs, whose scan
+carries start replicated and become shard-varying), which means NOTHING
+in jax verifies the replication contract the drivers rely on: every
+output ``solve_sharded`` declares replicated (``out_specs=P()``)
 — the objective, the row-partitioned families' full x, the replicated
 state leaves the elastic runtime checkpoints — must compute the SAME
 value on every shard. A shard-divergent "replicated" output is the
@@ -14,7 +15,7 @@ This pass recovers the guarantee statically. Each value in the
 shard_map body carries a taint: the set of mesh axes its value may vary
 over. The rules:
 
-  * inputs taint with the axes their ``in_names`` shard them over;
+  * inputs taint with the axes their ``in_specs`` shard them over;
   * ``axis_index(a)`` is the canonical divergence source — taint {a};
   * ``psum`` over axes A *removes* A from the operand taint (summing
     across an axis makes the result invariant along it) — this is the
@@ -162,24 +163,27 @@ def _find_shard_map(jaxpr):
     return None
 
 
-def _names_taint(names) -> Taint:
+def _spec_taint(spec) -> Taint:
+    """The mesh axes a PartitionSpec shards over (an entry is None, one
+    axis name, or a tuple of them)."""
     out: FrozenSet[str] = frozenset()
-    for axes in dict(names).values():
-        out |= frozenset(axes)
+    for entry in spec:
+        if entry is not None:
+            out |= frozenset((entry,) if isinstance(entry, str) else entry)
     return out
 
 
 def shard_map_out_taints(jaxpr) -> Tuple[List[Taint], List[Taint]]:
     """Locate the shard_map eqn inside a traced jaxpr and run the taint
     analysis over its body. Returns (out_taints, declared_out_taints):
-    the inferred per-output taints and what ``out_names`` declares
+    the inferred per-output taints and what ``out_specs`` declares
     (empty set = declared fully replicated)."""
     eqn = _find_shard_map(jaxpr)
     if eqn is None:
         raise ValueError("no shard_map equation found in jaxpr")
-    in_taints = [_names_taint(n) for n in eqn.params["in_names"]]
+    in_taints = [_spec_taint(p) for p in eqn.params["in_specs"]]
     out_taints = taint_jaxpr(eqn.params["jaxpr"], in_taints)
-    declared = [_names_taint(n) for n in eqn.params["out_names"]]
+    declared = [_spec_taint(p) for p in eqn.params["out_specs"]]
     return out_taints, declared
 
 

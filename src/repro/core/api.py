@@ -28,8 +28,7 @@ from typing import Any, Callable, Dict, Optional, Sequence, Tuple, Union
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax.sharding import AxisType, Mesh, PartitionSpec as P
 
 from repro.core.types import (FAMILIES, ProblemFamily, SolveState,
                               SolverConfig, SolverResult, SparseOperand)
@@ -85,13 +84,34 @@ def resolve_family(problem=None, family: Optional[object] = None
 # unpad plumbing, parameterized by the family's declared partition axis.
 # ---------------------------------------------------------------------------
 
-def _pad_to(x: np.ndarray, size: int, axis: int) -> np.ndarray:
+def _pad_to(x, size: int, axis: int, dtype):
+    """``x`` as a ``dtype`` device array, zero-padded along ``axis`` to
+    ``size`` (on the device: a dense A is never copied to the host)."""
+    x = jnp.asarray(x, dtype)
     pad = size - x.shape[axis]
     if pad == 0:
         return x
     widths = [(0, 0)] * x.ndim
     widths[axis] = (0, pad)
-    return np.pad(x, widths)
+    return jnp.pad(x, widths)
+
+
+def _shard_map(fn, mesh: Mesh, in_specs, out_specs):
+    """``jax.shard_map`` over ``mesh`` with its axes made Auto.
+
+    ``jax.make_mesh`` builds Explicit axes by default, under which the
+    partitioned outputs carry their sharding in their types and the
+    driver's unpadding slice (which cuts a sharded dimension) is a
+    sharding type error. The solve's layout is fully given by the specs,
+    so the driver runs on an Auto view of the same devices. The
+    replication contract is checked by ``repro.analysis.replication``:
+    ``check_vma`` rejects the kernel-SVM and logistic-regression
+    programs (their scan carries start replicated and become
+    shard-varying)."""
+    auto = Mesh(mesh.devices, mesh.axis_names,
+                axis_types=(AxisType.Auto,) * len(mesh.axis_names))
+    return jax.shard_map(fn, mesh=auto, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def _axis_size(mesh: Mesh, axes: AxisNames) -> int:
@@ -204,11 +224,10 @@ def solve_sharded(problem, cfg: SolverConfig, mesh: Mesh,
         A_arg = _stack_sparse_shards(problem.A, n_shards, part_axis,
                                      padded, cfg.dtype)
     else:
-        A_arg = jnp.asarray(
-            _pad_to(np.asarray(problem.A), padded, part_axis), cfg.dtype)
-    b = np.asarray(problem.b)
+        A_arg = _pad_to(problem.A, padded, part_axis, cfg.dtype)
+    b = problem.b
     if fam.partition == "row":
-        b = _pad_to(b, padded, 0)
+        b = _pad_to(b, padded, 0, cfg.dtype)
 
     vec, a_spec, b_spec, x_out = _specs(fam, axes)
     aux_specs = tuple(vec if layout == "partition" else P()
@@ -221,9 +240,8 @@ def solve_sharded(problem, cfg: SolverConfig, mesh: Mesh,
     in_specs = [vec if sparse else a_spec, b_spec]
     args = [A_arg, jnp.asarray(b, cfg.dtype)]
     if x0 is not None:
-        x0 = np.asarray(x0)
         if fam.x0_layout == "partition":
-            x0 = _pad_to(x0, padded, 0)
+            x0 = _pad_to(x0, padded, 0, cfg.dtype)
             in_specs.append(vec)
         else:
             in_specs.append(P())
@@ -235,9 +253,9 @@ def solve_sharded(problem, cfg: SolverConfig, mesh: Mesh,
                 f"family {fam.name!r} declares no state_layout — it "
                 f"cannot resume from a SolveState")
         for name, lay in layout:
-            leaf = np.asarray(state.carry[name])
+            leaf = state.carry[name]
             if lay == "partition":
-                leaf = _pad_to(leaf, padded, 0)
+                leaf = _pad_to(leaf, padded, 0, cfg.dtype)
                 in_specs.append(vec)
             else:
                 in_specs.append(P())
@@ -262,22 +280,27 @@ def solve_sharded(problem, cfg: SolverConfig, mesh: Mesh,
                           for name, _ in layout)
         return outs
 
-    fn = shard_map(local_solve, mesh=mesh, in_specs=tuple(in_specs),
-                   out_specs=(x_out, P()) + aux_specs + state_specs,
-                   check_rep=False)
-    out = jax.jit(fn)(*args)
+    out_layout = ((("x", "partition" if fam.partition == "col"
+                    else "replicated"), ("objective", "replicated"))
+                  + tuple(fam.aux_out) + tuple(layout))
+    sharded = _shard_map(local_solve, mesh, tuple(in_specs),
+                         (x_out, P()) + aux_specs + state_specs)
+
+    def solve_and_unpad(*a):
+        # the padded tail of every partitioned output is cut inside the
+        # one compiled program.
+        return tuple(v[:orig] if lay == "partition" else v
+                     for (_, lay), v in zip(out_layout, sharded(*a)))
+
+    out = jax.jit(solve_and_unpad)(*args)
     x, objective = out[0], out[1]
-    if fam.partition == "col":
-        x = x[:orig]
     n_aux = len(fam.aux_out)
-    aux = {k: (v[:orig] if layout_ == "partition" else v)
-           for (k, layout_), v in zip(fam.aux_out, out[2:2 + n_aux])}
+    aux = {k: v for (k, _), v in zip(fam.aux_out, out[2:2 + n_aux])}
     if layout:
         start = 0 if state is None else int(state.iteration)
         aux["state"] = SolveState(
             start + cfg.iterations,
-            {name: (v[:orig] if lay == "partition" else v)
-             for (name, lay), v in zip(layout, out[2 + n_aux:])})
+            {name: v for (name, _), v in zip(layout, out[2 + n_aux:])})
     return SolverResult(x=x, objective=objective, aux=aux)
 
 
@@ -304,8 +327,7 @@ def lower_solve(family: object, cfg: SolverConfig, mesh: Mesh,
         res = fam.solve(prob, cfg, axis_name=axes)
         return res.x, res.objective
 
-    fn = shard_map(local_solve, mesh=mesh, in_specs=(a_spec, b_spec),
-                   out_specs=(x_out, P()), check_rep=False)
+    fn = _shard_map(local_solve, mesh, (a_spec, b_spec), (x_out, P()))
     return jax.jit(fn).lower(jax.ShapeDtypeStruct((m, n), dtype),
                              jax.ShapeDtypeStruct((m,), dtype))
 
@@ -395,10 +417,8 @@ def trace_sharded(family: object, cfg: SolverConfig, mesh: Mesh,
                       for _, lay in fam.aux_out)
     state_specs = tuple(vec if lay == "partition" else P()
                         for _, lay in layout)
-    fn = shard_map(local_solve, mesh=mesh,
-                   in_specs=(vec if sparse else a_spec, b_spec),
-                   out_specs=(x_out, P()) + aux_specs + state_specs,
-                   check_rep=False)
+    fn = _shard_map(local_solve, mesh, (vec if sparse else a_spec, b_spec),
+                    (x_out, P()) + aux_specs + state_specs)
     jaxpr = jax.make_jaxpr(fn)(A_arg,
                                jax.ShapeDtypeStruct((b_len,), dtype))
     out_layout = (

@@ -6,7 +6,9 @@ The inner-loop kernels hold the (s*mu, s*mu) replicated Gram/kernel
 block resident in VMEM; the blocked-ELL SpMM holds its dense right
 operand (plus the gathered values/indices and the output tile) resident.
 Both share one budget: reject configurations that would not leave room
-(~16 MB on v5e; we cap the resident working set at half of it). The
+(v5e's default scoped VMEM limit is 16 MiB — the TPU compiler accepts a
+kernel resident set up to it and refuses one above it; we cap the
+modeled resident working set at half of it). The
 chosen implementation is an explicit, queryable decision that warns
 ONCE per configuration when a requested Pallas route has to fall back
 — the solvers surface it in ``SolverResult.aux["inner_impl"]`` /

@@ -23,10 +23,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams; support both.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or pltpu.TPUCompilerParams
-
 
 def _gram_kernel(x_ref, y_ref, o_ref, acc_ref):
     k = pl.program_id(2)
@@ -69,7 +65,7 @@ def gram_t_pallas(x, y, *, block_m: int = 256, block_i: int = 128,
         out_specs=pl.BlockSpec((block_i, block_j), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((p, q), jnp.float32),
         scratch_shapes=[pltpu.VMEM((block_i, block_j), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, y)

@@ -17,16 +17,20 @@ have this shape:
     points (the row-major ELL arrays as stored), D = the densified
     (n_loc, s*mu) sample, (m, s*mu) out.
 
-TPU mapping: grid = (R, K / bk) with the K-blocks innermost, so each
-output row tile stays resident while its ELL blocks accumulate; the
-per-row block count (the blocked-ELL nnz metadata) gates a ``pl.when``
-that skips fully-padded blocks. The row gathers from D use dynamic
-slices whose starts come from the index array, which is passed through
-``PrefetchScalarGridSpec`` scalar prefetch (SMEM) so the starts are
-available to address generation. Accumulation is f32.
+TPU mapping: grid = (R / 8,) over tiles of eight output rows, so every
+output block is a whole (8, Qp) f32 tile. A tile's ELL indices and
+values ride in SMEM (they are read one scalar at a time: the indices
+address D, the values scale the gathered row), D stays resident in VMEM
+for the whole grid, and each ELL slot gathers one row of D with a
+dynamic sublane slice and accumulates it in f32. The slot loop of a
+tile stops at the tile's active block count (the blocked-ELL nnz
+metadata, scalar-prefetched), so fully padded blocks cost nothing.
+Padded slots hold index 0 and value 0, so the slots a tile runs for its
+shorter rows are exact.
 
 VMEM budget: D at (C, Q) * 4 B dominates; ``dispatch.spmm_vmem_ok``
-rejects configurations above ~8 MB (half of v5e's ~16 MB VMEM).
+rejects configurations above 8 MiB (half of v5e's 16 MiB default scoped
+VMEM).
 """
 from __future__ import annotations
 
@@ -36,25 +40,19 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _make_kernel(K: int, bk: int, Q: int):
-    def kernel(idx_ref, nnb_ref, vals_ref, D_ref, o_ref):
-        r, kb = pl.program_id(0), pl.program_id(1)
+_TILE_ROWS = 8
 
-        @pl.when(kb == 0)
-        def _init():
-            o_ref[...] = jnp.zeros_like(o_ref)
 
-        # blocked-ELL skip: K-blocks at or past this row's active count
-        # are pure padding (zero values by construction).
-        @pl.when(kb < nnb_ref[r])
-        def _accumulate():
-            def body(t, acc):
-                c = idx_ref[r * K + kb * bk + t]
-                row = pl.load(D_ref, (pl.dslice(c, 1), slice(None)))
-                return acc + vals_ref[0, t] * row
+def _make_kernel(ell_block: int):
+    def kernel(tile_blocks_ref, idx_ref, vals_ref, D_ref, o_ref):
+        slots = tile_blocks_ref[pl.program_id(0)] * ell_block
+        for i in range(_TILE_ROWS):
+            def body(t, acc, i=i):
+                row = D_ref[pl.ds(idx_ref[i, t], 1), :]
+                return acc + vals_ref[i, t] * row
 
-            o_ref[...] += jax.lax.fori_loop(
-                0, bk, body, jnp.zeros((1, Q), jnp.float32))
+            o_ref[i:i + 1, :] = jax.lax.fori_loop(
+                0, slots, body, jnp.zeros((1, o_ref.shape[1]), jnp.float32))
 
     return kernel
 
@@ -68,20 +66,27 @@ def ell_spmm_pallas(vals, idx, blocks, D, *, ell_block: int,
     C, Q = D.shape
     if K % ell_block != 0:
         raise ValueError(f"K={K} is not a multiple of ell_block={ell_block}")
-
+    pad = (-R) % _TILE_ROWS
+    Rp = R + pad
+    vals = jnp.pad(vals.astype(jnp.float32), ((0, pad), (0, 0)))
+    idx = jnp.pad(idx.astype(jnp.int32), ((0, pad), (0, 0)))
+    tile_blocks = jnp.pad(blocks.astype(jnp.int32), (0, pad)).reshape(
+        Rp // _TILE_ROWS, _TILE_ROWS).max(axis=1)
+    tile = pl.BlockSpec((_TILE_ROWS, K), lambda r, *_: (r, 0),
+                        memory_space=pltpu.SMEM)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,      # flat indices + per-row block counts
-        grid=(R, K // ell_block),
-        in_specs=[
-            pl.BlockSpec((1, ell_block), lambda r, kb, *_: (r, kb)),
-            pl.BlockSpec((C, Q), lambda r, kb, *_: (0, 0)),  # resident
-        ],
-        out_specs=pl.BlockSpec((1, Q), lambda r, kb, *_: (r, 0)),
+        num_scalar_prefetch=1,      # per-tile active block counts
+        grid=(Rp // _TILE_ROWS,),
+        in_specs=[tile, tile,
+                  pl.BlockSpec(memory_space=pltpu.VMEM)],   # resident D
+        out_specs=pl.BlockSpec((_TILE_ROWS, Q), lambda r, *_: (r, 0)),
     )
-    return pl.pallas_call(
-        _make_kernel(K, ell_block, Q),
+    out = pl.pallas_call(
+        _make_kernel(ell_block),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((R, Q), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((Rp, Q), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
         interpret=interpret,
-    )(idx.reshape(-1).astype(jnp.int32), blocks.astype(jnp.int32),
-      vals.astype(jnp.float32), D.astype(jnp.float32))
+    )(tile_blocks, idx, vals, D.astype(jnp.float32))
+    return out[:R]

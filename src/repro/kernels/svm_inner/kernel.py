@@ -6,12 +6,18 @@ Same TPU rethinking as ``repro.kernels.sa_inner``: the paper's
 O((s*mu)^2) state — the regularized block matrix G (linear Gram or
 kernel block), the projections, labels, gathered duals and the growing
 theta history — in VMEM, with zero intermediate HBM round-trips. Per
-step: the t<j cross-term GEMV against G's off-diagonal blocks, the
-power-iteration step size on the diagonal block (skipped for mu = 1,
-where the (1, 1) block IS the eigenvalue), and the clipped dual update.
+step: the t<j cross-term product against G, the clipped dual update
+with the diagonal block's eigenvalue as step size, and the step's
+dual-objective increment (a second product against G).
 
-VMEM budget: the dominant resident is G at (s*mu)^2 * 4 bytes; ops.py
-rejects configurations above ~8 MB (half of v5e's ~16 MB VMEM).
+Layout: the lane-dense (1, P) rows of ``repro.kernels.common`` — step j
+computes on every lane and commits block j's (``blk == j``); duals at
+repeated rows are kept current by a running collision update; the s
+diagonal-block eigenvalues come in precomputed (they depend on G only).
+
+VMEM budget: the dominant resident is G at P^2 * 4 bytes; ops.py
+rejects configurations whose (s*mu)^2 * 4 B exceeds the 8 MiB cap (half
+of v5e's 16 MiB default scoped VMEM).
 
 Single grid point — the loop is inherently sequential; these flops are
 the SA trade's latency-free replicated work.
@@ -21,69 +27,50 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import power_iter_max_eig
+from repro.kernels.common import (block_max_eigs, lane_rows, nt_matvec,
+                                  padded_width, running_collisions)
 
 
-def _make_kernel(s: int, mu: int, gamma: float, nu: float,
-                 power_iters: int):
-    smu = s * mu
+def _make_kernel(s: int, mu: int, gamma: float, nu: float):
     finite_nu = nu == nu and nu != float("inf")
 
     def _clip(x):
         lo = jnp.maximum(x, 0.0)
         return jnp.minimum(lo, nu) if finite_nu else lo
 
-    def kernel(G_ref, proj_ref, b_ref, avals_ref, idx_ref,
-               theta_ref, dual_ref):
-        theta_ref[...] = jnp.zeros_like(theta_ref)
-        dual_ref[...] = jnp.zeros_like(dual_ref)
-        idx_flat = idx_ref[...].reshape(1, smu)
+    def kernel(idx_s_ref, G_ref, blk_ref, idx_ref, proj_ref, b_ref,
+               avals_ref, eig_ref, theta_ref, dual_ref):
+        blk = blk_ref[...]
+        idx = idx_ref[...]
+        proj, b = proj_ref[...], b_ref[...]
+        eig = eig_ref[...]
 
-        def body(j, _):
-            b_j = b_ref[j, :]
-            Gj = pl.load(G_ref, (pl.dslice(j * mu, mu), slice(None)))
-            # (mu, s*mu)
-
-            th_flat = theta_ref[...].reshape(1, smu)
-            bt_flat = b_ref[...].reshape(1, smu) * th_flat
-            t_ids = jax.lax.broadcasted_iota(jnp.int32, (s, mu), 0)
-            mask = (t_ids < j).astype(jnp.float32).reshape(1, smu)
-
-            cross = jnp.dot(Gj, (mask * bt_flat).reshape(smu, 1),
-                            preferred_element_type=jnp.float32)   # (mu, 1)
-            rj = proj_ref[j, :] + cross[:, 0]
-
-            Gjj = pl.load(G_ref, (pl.dslice(j * mu, mu),
-                                  pl.dslice(j * mu, mu)))
-            # mu = 1: the diagonal "block" is the eigenvalue itself.
-            vmax = Gjj[0, 0] if mu == 1 \
-                else power_iter_max_eig(Gjj, power_iters)
-
-            # collision-corrected alpha at this block's rows.
-            idx_j = pl.load(idx_ref, (pl.dslice(j, 1), slice(None)))
-            eq = (idx_j.reshape(mu, 1) == idx_flat).astype(jnp.float32)
-            beta = avals_ref[j, :] + jnp.dot(
-                eq, (mask * th_flat).reshape(smu, 1),
-                preferred_element_type=jnp.float32)[:, 0]
-
-            g = b_j * rj - 1.0 + gamma * beta
+        def body(j, carry):
+            th, dual, beta = carry
+            sel = blk == j
+            cross = nt_matvec(jnp.where(blk < j, b * th, 0.0), G_ref[...])
+            g = b * (proj + cross) - 1.0 + gamma * beta
             gbar = jnp.abs(_clip(beta - g) - beta)
-            theta = jnp.where(gbar != 0.0, _clip(beta - g / vmax) - beta,
+            theta = jnp.where(gbar != 0.0, _clip(beta - g / eig) - beta,
                               0.0)
+            th = jnp.where(sel, theta, th)
+            # dual increment theta^T g + 1/2 (b theta)^T G_jj (b theta)
+            bt = jnp.where(sel, b * theta, 0.0)
+            delta = jnp.sum(jnp.where(sel, theta * g, 0.0), axis=1,
+                            keepdims=True) \
+                + 0.5 * jnp.sum(nt_matvec(bt, G_ref[...]) * bt, axis=1,
+                                keepdims=True)
+            dual = jnp.where(sel, delta, dual)
+            beta = running_collisions(beta, idx, idx_s_ref, th, j, mu)
+            return th, dual, beta
 
-            bt = b_j * theta
-            w = jnp.dot(bt.reshape(1, mu), Gjj,
-                        preferred_element_type=jnp.float32)        # (1, mu)
-            delta = jnp.sum(theta * g) + 0.5 * jnp.sum(w[0, :] * bt)
-
-            pl.store(theta_ref, (pl.dslice(j, 1), slice(None)),
-                     theta.reshape(1, mu))
-            pl.store(dual_ref, (pl.dslice(j, 1), slice(None)),
-                     delta.reshape(1, 1))
-            return 0
-
-        jax.lax.fori_loop(0, s, body, 0)
+        zero = jnp.zeros_like(proj)
+        th, dual, _ = jax.lax.fori_loop(
+            0, s, body, (zero, zero, avals_ref[...]))
+        theta_ref[...] = th
+        dual_ref[...] = dual
 
     return kernel
 
@@ -94,13 +81,22 @@ def svm_inner_pallas(G, proj, b_sel, a_vals, idx, *, gamma: float,
     """Run the s-step SVM inner loop in one kernel launch. All inputs are
     the replicated post-Allreduce quantities; see ref.py for shapes."""
     s, mu = proj.shape
-    kernel = _make_kernel(s, mu, float(gamma), float(nu), power_iters)
+    smu = s * mu
+    P = padded_width(smu)
+    G = G.astype(jnp.float32)
+    Gp = jnp.pad(G, ((0, P - smu), (0, P - smu)))
+    blk, idx_row, pr, b, av, eig = lane_rows(
+        P, mu, idx, [proj, b_sel, a_vals,
+                     jnp.repeat(block_max_eigs(G, s, mu, power_iters),
+                                mu)])
+    vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
     theta, duals = pl.pallas_call(
-        kernel,
-        out_shape=(jax.ShapeDtypeStruct((s, mu), jnp.float32),
-                   jax.ShapeDtypeStruct((s, 1), jnp.float32)),
+        _make_kernel(s, mu, float(gamma), float(nu)),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)] + [vmem] * 7,
+        out_specs=(vmem, vmem),
+        out_shape=(jax.ShapeDtypeStruct((1, P), jnp.float32),
+                   jax.ShapeDtypeStruct((1, P), jnp.float32)),
         interpret=interpret,
-    )(G.astype(jnp.float32), proj.astype(jnp.float32),
-      b_sel.astype(jnp.float32), a_vals.astype(jnp.float32),
-      idx.astype(jnp.int32))
-    return theta, duals[:, 0]
+    )(idx.reshape(smu).astype(jnp.int32), Gp, blk, idx_row, pr, b, av,
+      eig)
+    return theta[0, :smu].reshape(s, mu), duals[0, :smu:mu]

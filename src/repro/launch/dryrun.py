@@ -35,14 +35,13 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs import get_config, list_archs
 from repro.configs.base import SHAPES, ArchConfig, ShapeConfig, input_specs
-from repro.launch.mesh import make_production_mesh, set_mesh
+from repro.launch.mesh import make_production_mesh
 from repro.models import lm
 from repro.optim.adamw import AdamW
 from repro.parallel.sharding import (batch_partition_specs, dp_axes,
                                      param_partition_specs)
 from repro.roofline.analysis import (HW_V5E, collective_bytes_from_hlo,
-                                     cost_analysis_dict, model_flops,
-                                     roofline_terms, two_point_fit)
+                                     model_flops, roofline_terms, two_point_fit)
 
 RESULTS_DIR = os.path.join(os.path.dirname(__file__),
                            "..", "..", "..", "results", "dryrun")
@@ -221,14 +220,14 @@ def run_cell(arch_name: str, shape_name: str, multi_pod: bool,
     t0 = time.time()
     try:
         # ---- full-depth compile: proves sharding + memory fit ----------
-        # set_mesh context: the in-model with_sharding_constraint hints
+        # jax.set_mesh context: the in-model with_sharding_constraint hints
         # (SP activations, EP buffers, split-KV) need a mesh during trace.
         fn, args, in_sh, out_sh, donate = build_step(arch, shape, mesh,
                                                      opts)
         jit_kw = {"in_shardings": in_sh, "donate_argnums": donate}
         if out_sh is not None:
             jit_kw["out_shardings"] = out_sh
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = jax.jit(fn, **jit_kw).lower(*args)
             compiled = lowered.compile()
         result["compile_s"] = round(time.time() - t0, 1)
@@ -270,9 +269,9 @@ def run_cell(arch_name: str, shape_name: str, multi_pod: bool,
             if out_shg is not None:
                 jkw["out_shardings"] = out_shg
             from repro.kernels.flash_attention.ops import cost_exact_mode
-            with set_mesh(mesh), cost_exact_mode():
+            with jax.set_mesh(mesh), cost_exact_mode():
                 cg = jax.jit(fng, **jkw).lower(*argsg).compile()
-            ca = cost_analysis_dict(cg)
+            ca = cg.cost_analysis()
             coll = collective_bytes_from_hlo(cg.as_text())
             pts[g] = {"flops": float(ca.get("flops", 0.0)),
                       "bytes": float(ca.get("bytes accessed", 0.0)),
@@ -289,7 +288,7 @@ def run_cell(arch_name: str, shape_name: str, multi_pod: bool,
                                 "collective_bytes": coll_dev}
 
         # ---- roofline terms ---------------------------------------------
-        terms = roofline_terms(flops_dev, bytes_dev, coll_dev)
+        terms = roofline_terms(flops_dev, bytes_dev, coll_dev, HW_V5E)
         result["roofline"] = terms
         n_active = arch.active_param_count() if arch.n_experts \
             else lm.param_count(arch)

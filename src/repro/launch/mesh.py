@@ -22,14 +22,3 @@ def make_production_mesh(*, multi_pod: bool = False):
 def make_mesh(shape, axes):
     """Arbitrary mesh helper (tests, examples)."""
     return jax.make_mesh(tuple(shape), tuple(axes))
-
-
-def set_mesh(mesh):
-    """Context manager installing ``mesh`` for the enclosed traces.
-
-    ``jax.set_mesh`` where available; on older jax the Mesh object itself
-    is the context manager.
-    """
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh

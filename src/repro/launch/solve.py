@@ -16,6 +16,7 @@ import time
 
 from repro import api
 from repro.api import FAMILIES, KERNELS, SolverConfig
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def build_parser() -> argparse.ArgumentParser:
@@ -137,6 +138,7 @@ def main(argv=None):
     if args.list_families:
         print(list_families())
         return
+    enable_compile_cache()
     family = FAMILIES[args.problem]
     if args.mu is None:
         args.mu = family.default_mu

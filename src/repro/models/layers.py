@@ -16,7 +16,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.kernels.flash_attention.ops import flash_attention
-from repro.parallel.sharding import get_abstract_mesh as _get_abstract_mesh
 
 
 def maybe_shard(x, spec: P):
@@ -24,7 +23,7 @@ def maybe_shard(x, spec: P):
     a mesh lacking the named axes) is in context — so model code runs
     unchanged on a single CPU device and under the production mesh."""
     try:
-        mesh = _get_abstract_mesh()
+        mesh = jax.sharding.get_abstract_mesh()
         if mesh.empty:
             return x
         names = set()
@@ -247,7 +246,7 @@ def _moe_buffer_spec(n_experts: int, ep_axis: Optional[str]):
     if ep_axis is None:
         return None
     try:
-        mesh = _get_abstract_mesh()
+        mesh = jax.sharding.get_abstract_mesh()
         if mesh.empty or ep_axis not in mesh.axis_names:
             return None
         size = mesh.shape[ep_axis]

@@ -30,7 +30,6 @@ from jax.sharding import PartitionSpec as P
 from repro.configs.base import ArchConfig
 from repro.models import layers as L
 from repro.models import recurrent as R
-from repro.parallel.sharding import get_abstract_mesh as _get_abstract_mesh
 
 
 # ---------------------------------------------------------------------------
@@ -220,7 +219,7 @@ def _scan_layers(params, x, arch: ArchConfig, fn, remat: str = "none",
             outs[slot] = out
         if shard_acts:
             from repro.parallel.sharding import activation_spec
-            mesh = _get_abstract_mesh()
+            mesh = jax.sharding.get_abstract_mesh()
             if not mesh.empty:
                 x = L.maybe_shard(x, activation_spec(mesh.axis_names))
         return x, outs
@@ -251,7 +250,7 @@ def _sinusoid(positions, d: int):
 
 def _embed(params, arch: ArchConfig, tokens, extras: Dict, pos0=0):
     x = params["embed"][tokens].astype(arch.jnp_dtype)
-    mesh = _get_abstract_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     if not mesh.empty:
         from repro.parallel.sharding import activation_spec
         x = L.maybe_shard(x, activation_spec(mesh.axis_names))

@@ -31,19 +31,6 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
-def get_abstract_mesh():
-    """The mesh currently in context (``repro.launch.mesh.set_mesh``).
-
-    ``jax.sharding.get_abstract_mesh`` where available; on older jax the
-    ``with mesh:`` context populates the legacy thread-resources env,
-    whose physical mesh exposes the same ``empty`` / ``axis_names`` /
-    ``shape`` surface the callers need.
-    """
-    if hasattr(jax.sharding, "get_abstract_mesh"):
-        return jax.sharding.get_abstract_mesh()
-    from jax._src import mesh as _mesh_lib
-    return _mesh_lib.thread_resources.env.physical_mesh
-
 _COL = ("wq", "wk", "wv", "w_gate", "w_up", "w_z", "w_i", "w_f", "w_o")
 _ROW = ("wo", "w_down")
 _REPL = ("scale", "b_decay", "b_f", "router", "w_decay",

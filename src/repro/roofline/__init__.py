@@ -1,4 +1,5 @@
-from repro.roofline.analysis import (HW_V5E, CollectiveStats,
+from repro.roofline.analysis import (HARDWARE, HW_V5E, CollectiveStats,
                                      collective_bytes_from_hlo,
                                      collective_stats_from_hlo,
-                                     roofline_terms, model_flops)
+                                     hardware_for, roofline_terms,
+                                     model_flops)

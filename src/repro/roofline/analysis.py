@@ -43,8 +43,24 @@ class Hardware:
     hbm_bytes: float         # per chip
 
 
+# Published per-chip peaks, keyed by ``jax.Device.device_kind``.
+# TPU v5e: Google Cloud documentation, "TPU v5e" — 197 TFLOP/s (bf16),
+# 16 GB of HBM at 819 GB/s, 1,600 Gbit/s of chip-to-chip interconnect
+# (four links of 50 GB/s).
 HW_V5E = Hardware(name="tpu-v5e", peak_flops=197e12, hbm_bw=819e9,
                   ici_bw=50e9, hbm_bytes=16e9)
+HARDWARE: Mapping[str, Hardware] = {"TPU v5 lite": HW_V5E}
+
+
+def hardware_for(device_kind: str) -> Hardware:
+    """The published peaks of a device kind; an unknown kind is an
+    error, never a default."""
+    try:
+        return HARDWARE[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r} "
+            f"(known: {sorted(HARDWARE)})") from None
 
 _COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter", "all-to-all",
                 "collective-permute")
@@ -143,15 +159,6 @@ def collective_bytes_from_hlo(hlo_text: str) -> Dict[str, Any]:
     return out
 
 
-def cost_analysis_dict(compiled) -> Dict[str, float]:
-    """``compiled.cost_analysis()`` normalized across jax versions:
-    older jax returns a one-element list of dicts, newer jax the dict."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca
-
-
 def two_point_fit(cost1: float, cost2: float, n1: int, n2: int,
                   n_target: int) -> float:
     """cost(n) = fixed + n * per_unit, fit on (n1, cost1), (n2, cost2)."""
@@ -161,7 +168,7 @@ def two_point_fit(cost1: float, cost2: float, n1: int, n2: int,
 
 
 def roofline_terms(flops_per_dev: float, bytes_per_dev: float,
-                   coll_bytes_per_dev: float, hw: Hardware = HW_V5E,
+                   coll_bytes_per_dev: float, hw: Hardware,
                    mac_correction: float = 1.0) -> Dict[str, float]:
     """The three terms (seconds) + the bound classification."""
     compute = flops_per_dev * mac_correction / hw.peak_flops

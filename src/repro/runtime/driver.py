@@ -28,7 +28,6 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.checkpoint import CheckpointManager
-from repro.launch.mesh import set_mesh
 from repro.configs.base import ArchConfig
 from repro.data.tokens import TokenPipeline
 from repro.models import lm
@@ -157,7 +156,7 @@ class Trainer:
                                             self.mesh)
         self.sspecs = self.optimizer.state_specs(self.pspecs)
         if fresh:
-            with set_mesh(self.mesh):
+            with jax.set_mesh(self.mesh):
                 params = lm.init_params(self.arch,
                                         jax.random.key(self.cfg.seed))
                 params = jax.device_put(params, self._ns(self.pspecs))

@@ -116,11 +116,11 @@ def test_collective_budget_splits_loop_vs_amortized():
         out, _ = jax.lax.scan(body, x, None, length=4)
         return jax.lax.psum(out, "i")                    # tail/amortized
 
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     mesh = jax.make_mesh((1,), ("i",))
     fn = shard_map(f, mesh=mesh, in_specs=(P(),), out_specs=P(),
-                   check_rep=False)
+                   check_vma=False)
     budget = collective_budget(
         jax.make_jaxpr(fn)(jax.ShapeDtypeStruct((8,), jnp.float32)))
     assert budget.per_iteration["all-reduce"] == 1
@@ -130,7 +130,7 @@ def test_collective_budget_splits_loop_vs_amortized():
 
 
 def test_taint_axis_index_and_while_predicate():
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     mesh = jax.make_mesh((1,), ("i",))
 
@@ -147,7 +147,7 @@ def test_taint_axis_index_and_while_predicate():
         return jax.lax.psum(x, "i"), looped
 
     fn = shard_map(f, mesh=mesh, in_specs=(P("i"),), out_specs=(P(), P()),
-                   check_rep=False)
+                   check_vma=False)
     outs, _ = shard_map_out_taints(
         jax.make_jaxpr(fn)(jax.ShapeDtypeStruct((8,), jnp.float32)))
     assert outs[0] == frozenset()          # psum'd: replicated
@@ -155,8 +155,7 @@ def test_taint_axis_index_and_while_predicate():
 
 
 def test_find_float_narrowing_reports_site():
-    from jax.experimental import enable_x64
-    with enable_x64():
+    with jax.enable_x64(True):
         j = jax.make_jaxpr(lambda x: (x * 2.0).astype(jnp.float32))(
             jax.ShapeDtypeStruct((4,), jnp.float64))
     hits = find_float_narrowing(j)
@@ -479,7 +478,7 @@ def test_kernel_safety_pass_green_over_all_packages():
 # ---------------------------------------------------------------------------
 
 def _scan_cond_taints(use_tainted_branch):
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     mesh = jax.make_mesh((1,), ("i",))
 
@@ -498,7 +497,7 @@ def _scan_cond_taints(use_tainted_branch):
         return jax.lax.psum(x, "i"), out
 
     fn = shard_map(f, mesh=mesh, in_specs=(P("i"),),
-                   out_specs=(P(), P()), check_rep=False)
+                   out_specs=(P(), P()), check_vma=False)
     outs, _ = shard_map_out_taints(
         jax.make_jaxpr(fn)(jax.ShapeDtypeStruct((8,), jnp.float32)))
     return outs
@@ -516,7 +515,7 @@ def test_clean_cond_inside_scan_stays_untainted():
 
 
 def test_taint_cond_predicate_inside_scan_carry():
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     mesh = jax.make_mesh((1,), ("i",))
 
@@ -534,7 +533,7 @@ def test_taint_cond_predicate_inside_scan_carry():
         return jax.lax.psum(x, "i"), out
 
     fn = shard_map(f, mesh=mesh, in_specs=(P("i"),),
-                   out_specs=(P(), P()), check_rep=False)
+                   out_specs=(P(), P()), check_vma=False)
     outs, _ = shard_map_out_taints(
         jax.make_jaxpr(fn)(jax.ShapeDtypeStruct((8,), jnp.float32)))
     assert outs[1] == frozenset({"i"})

@@ -30,7 +30,7 @@ def test_error_feedback_step_identity():
     gradient splits into applied + residual with no leakage —
     (g + r_in) == q * scale + r_out bitwise in f32. This is what makes
     the cumulative applied update track the cumulative true gradient."""
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.optim.compress import compressed_psum
@@ -45,7 +45,7 @@ def test_error_feedback_step_identity():
         return out["g"], ef2.residual["g"]
 
     fn = shard_map(body, mesh=mesh, in_specs=(P(), P()),
-                   out_specs=(P(), P()), check_rep=False)
+                   out_specs=(P(), P()), check_vma=False)
     r = jnp.zeros(16)
     for i in range(8):
         g = jnp.asarray(rng.standard_normal(16), jnp.float32) * (10.0 ** (i - 4))
@@ -63,7 +63,7 @@ def test_error_feedback_accumulation_drains():
     """A constant gradient too small to survive quantization alone is
     NOT lost: the residual accumulates until it crosses a quantization
     step and drains into the applied update (EF's raison d'etre)."""
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.optim.compress import compressed_psum
@@ -77,7 +77,7 @@ def test_error_feedback_accumulation_drains():
         return out["g"], ef2.residual["g"]
 
     fn = shard_map(body, mesh=mesh, in_specs=(P(), P()),
-                   out_specs=(P(), P()), check_rep=False)
+                   out_specs=(P(), P()), check_vma=False)
     # one dominant coordinate sets the scale; the tiny coordinate is far
     # below scale/2 and would round to zero every step without EF.
     g = jnp.asarray([1.0, 1e-3], jnp.float32)

@@ -12,7 +12,7 @@ SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
 
 def _run(code: str, timeout=900):
-    env = dict(os.environ, PYTHONPATH=SRC)
+    env = dict(os.environ, PYTHONPATH=SRC, JAX_PLATFORMS="cpu")
     out = subprocess.run([sys.executable, "-c", code], env=env,
                          capture_output=True, text=True, timeout=timeout)
     assert out.returncode == 0, (out.stdout[-2000:], out.stderr[-3000:])

@@ -67,7 +67,7 @@ def test_error_feedback_preserves_sum():
     applied update tracks the cumulative true gradient."""
     from repro.optim.compress import compressed_psum
     import functools
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     # single-device axis: psum over a size-1 mesh axis is identity, but
@@ -84,7 +84,7 @@ def test_error_feedback_preserves_sum():
                                        "dp", n_shards=1)
             return out["g"], ef2.residual["g"]
         fn = shard_map(body, mesh=mesh, in_specs=(P(), P()),
-                       out_specs=(P(), P()), check_rep=False)
+                       out_specs=(P(), P()), check_vma=False)
         out, res = fn(g, ef.residual["g"])
         ef = ErrorFeedback(residual={"g": res})
         applied = applied + out

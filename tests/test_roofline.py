@@ -5,8 +5,7 @@ import pytest
 
 from repro.roofline.analysis import (HW_V5E, collective_bytes_from_hlo,
                                      collective_stats_from_hlo,
-                                     cost_analysis_dict, model_flops,
-                                     roofline_terms, two_point_fit)
+                                     model_flops, roofline_terms, two_point_fit)
 
 SAMPLE_HLO = """
 ENTRY %main {
@@ -109,13 +108,13 @@ def test_two_point_fit_exact_linear():
 
 def test_roofline_terms_classification():
     t = roofline_terms(flops_per_dev=1e15, bytes_per_dev=1e9,
-                       coll_bytes_per_dev=1e9)
+                       coll_bytes_per_dev=1e9, hw=HW_V5E)
     assert t["dominant"] == "compute"
     t = roofline_terms(flops_per_dev=1e9, bytes_per_dev=1e13,
-                       coll_bytes_per_dev=1e9)
+                       coll_bytes_per_dev=1e9, hw=HW_V5E)
     assert t["dominant"] == "memory"
     t = roofline_terms(flops_per_dev=1e9, bytes_per_dev=1e9,
-                       coll_bytes_per_dev=1e13)
+                       coll_bytes_per_dev=1e13, hw=HW_V5E)
     assert t["dominant"] == "collective"
     assert 0 < t["roofline_fraction"] <= 1.0
 
@@ -132,7 +131,7 @@ def test_xla_flops_convention_is_2mnk():
     a = jax.ShapeDtypeStruct((256, 128), jnp.float32)
     b = jax.ShapeDtypeStruct((128, 64), jnp.float32)
     c = jax.jit(lambda x, y: x @ y).lower(a, b).compile()
-    flops = cost_analysis_dict(c)["flops"]
+    flops = c.cost_analysis()["flops"]
     assert flops == pytest.approx(2 * 256 * 128 * 64, rel=0.05)
 
 
@@ -145,7 +144,7 @@ def test_xla_scan_body_counted_once():
         return out
 
     x = jax.ShapeDtypeStruct((64, 64), jnp.float32)
-    fl8 = cost_analysis_dict(jax.jit(f).lower(x).compile())["flops"]
+    fl8 = jax.jit(f).lower(x).compile().cost_analysis()["flops"]
 
     def f1(x):
         def body(c, _):
@@ -153,5 +152,12 @@ def test_xla_scan_body_counted_once():
         out, _ = jax.lax.scan(body, x, None, length=1)
         return out
 
-    fl1 = cost_analysis_dict(jax.jit(f1).lower(x).compile())["flops"]
+    fl1 = jax.jit(f1).lower(x).compile().cost_analysis()["flops"]
     assert fl8 == pytest.approx(fl1, rel=0.01)
+
+
+def test_hardware_lookup_by_device_kind():
+    from repro.roofline.analysis import hardware_for
+    assert hardware_for("TPU v5 lite") is HW_V5E
+    with pytest.raises(ValueError, match="no published peaks"):
+        hardware_for("cpu")
