@@ -36,8 +36,10 @@ seams follow the phase structure every SA method shares:
 ``assemble`` builds the LOCAL (pre-reduce) payload, ``reduce`` performs
 the group's ONE collective, ``inner`` runs the s dependent updates on
 the replicated reduced data, ``defer`` applies the m/n-dimensional
-updates and stitches the objective trace. See DESIGN.md "The SA
-engine" for the contract and a family-authoring guide.
+updates and stitches the objective trace. Each seam runs inside the
+named scope of its phase (:mod:`repro.core.phases`), so every family's
+compiled instructions carry the phase they belong to. See DESIGN.md
+"The SA engine" for the contract and a family-authoring guide.
 """
 from __future__ import annotations
 
@@ -48,7 +50,7 @@ from typing import Callable, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.core import linalg
+from repro.core import linalg, phases
 from repro.core.sparse_exec import spmm_aux
 from repro.core.types import (SolveState, SolverResult, resume_carry)
 from repro.kernels import spmm
@@ -148,10 +150,11 @@ def gram_local(Y, vecs, use_pallas: bool = False):
     Y: (m_loc, s*mu) sampled columns; vecs: (m_loc, k) residual-like
     vectors. ``use_pallas`` routes the GEMM through the
     ``repro.kernels.gram`` Pallas kernel (f32 MXU accumulation)."""
-    rhs = jnp.concatenate([Y, vecs], axis=1)
-    if use_pallas:
-        return gram_t(Y, rhs, use_pallas=True).astype(Y.dtype)
-    return Y.T @ rhs
+    with phases.scope("gram"):
+        rhs = jnp.concatenate([Y, vecs], axis=1)
+        if use_pallas:
+            return gram_t(Y, rhs, use_pallas=True).astype(Y.dtype)
+        return Y.T @ rhs
 
 
 def gram_and_proj(Y, vecs, axis_name, symmetric: bool = False,
@@ -287,29 +290,36 @@ def run_program(prog: FamilyProgram, problem, cfg, axis_name=None,
     schema, and the Pallas↔ref impl labels."""
     carry0 = resume_carry(state, x0, prog.name)
     h0 = 0 if state is None else int(state.iteration)
-    ctx, carry = prog.setup(problem, cfg, axis_name, x0, carry0)
-    key = jax.random.key(cfg.seed)
-    s, H = cfg.s, cfg.iterations
-    sched = None if prog.schedule is None \
-        else prog.schedule(ctx, cfg, h0 + H)       # (h0 + H + 1,)
+    with phases.scope("setup"):
+        ctx, carry = prog.setup(problem, cfg, axis_name, x0, carry0)
+        key = jax.random.key(cfg.seed)
+        s, H = cfg.s, cfg.iterations
+        sched = None if prog.schedule is None \
+            else prog.schedule(ctx, cfg, h0 + H)   # (h0 + H + 1,)
 
     def group(carry, start, s_grp):
-        idxs = sample_all(key, lambda k: prog.sample(ctx, k),
-                          start, s_grp)            # (s_grp, mu)
-        win = None if sched is None else (
-            jax.lax.dynamic_slice(sched, (start,), (s_grp,)),
-            jax.lax.dynamic_slice(sched, (start + 1,), (s_grp,)))
+        with phases.scope("sample"):
+            idxs = sample_all(key, lambda k: prog.sample(ctx, k),
+                              start, s_grp)        # (s_grp, mu)
+            win = None if sched is None else (
+                jax.lax.dynamic_slice(sched, (start,), (s_grp,)),
+                jax.lax.dynamic_slice(sched, (start + 1,), (s_grp,)))
         # --- Communication: assemble locally, reduce ONCE ---
-        handle, local = prog.assemble(ctx, carry, idxs, s_grp)
-        payload = prog.reduce(ctx, local, idxs, s_grp)
+        with phases.scope("assemble"):
+            handle, local = prog.assemble(ctx, carry, idxs, s_grp)
+        with phases.scope("reduce"):
+            payload = prog.reduce(ctx, local, idxs, s_grp)
         # --- the s_grp dependent inner updates, then deferred apply ---
-        carry, inner_out = prog.inner(ctx, carry, handle, payload, idxs,
-                                      win, s_grp)
-        return prog.defer(ctx, carry, handle, inner_out, payload, idxs,
-                          win, s_grp)
+        with phases.scope("inner"):
+            carry, inner_out = prog.inner(ctx, carry, handle, payload,
+                                          idxs, win, s_grp)
+        with phases.scope("defer"):
+            return prog.defer(ctx, carry, handle, inner_out, payload,
+                              idxs, win, s_grp)
 
     carry, objs = run_grouped(group, carry, H, s, cfg.dtype, start=h0)
-    x, extra = prog.finalize(ctx, carry, sched)
+    with phases.scope("finalize"):
+        x, extra = prog.finalize(ctx, carry, sched)
     aux = dict(extra)
     aux["state"] = SolveState(h0 + H, dict(zip(prog.carry_names, carry)))
     itemsize = jnp.dtype(cfg.dtype).itemsize

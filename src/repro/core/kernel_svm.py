@@ -46,7 +46,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro.core import cost_model, linalg
+from repro.core import cost_model, linalg, phases
 from repro.core.engine import Ctx, FamilyProgram, run_program
 from repro.core.sparse_exec import (cross_block, prep_operand,
                                     row_block_ops, spmm_aux)
@@ -182,58 +182,67 @@ def kbdcd_svm(problem: SVMProblem, cfg: SolverConfig,
     the step size. The dual objective is tracked incrementally exactly
     as in ``bdcd_svm`` with G -> K_BB + gamma I (DESIGN.md).
     """
-    mu = cfg.block_size
-    gamma = jnp.asarray(problem.gamma, cfg.dtype)
-    nu = jnp.asarray(problem.nu, cfg.dtype)
-    key = jax.random.key(cfg.seed)
-    carry0 = resume_carry(state, alpha0, "kbdcd_svm")
-    start = 0 if state is None else int(state.iteration)
-    A, b, alpha, x, f, dual0 = _init_state(problem, cfg, axis_name, alpha0,
-                                           carry0)
-    take, _, densify, apply_t = row_block_ops(A, cfg)
-    norms_local = _local_norms(A, problem.kernel_spec.needs_norms)
-    m = A.shape[0]
-    eye_mu = jnp.eye(mu, dtype=cfg.dtype)
+    with phases.scope("setup"):
+        mu = cfg.block_size
+        gamma = jnp.asarray(problem.gamma, cfg.dtype)
+        nu = jnp.asarray(problem.nu, cfg.dtype)
+        key = jax.random.key(cfg.seed)
+        carry0 = resume_carry(state, alpha0, "kbdcd_svm")
+        start = 0 if state is None else int(state.iteration)
+        A, b, alpha, x, f, dual0 = _init_state(problem, cfg, axis_name,
+                                               alpha0, carry0)
+        take, _, densify, apply_t = row_block_ops(A, cfg)
+        norms_local = _local_norms(A, problem.kernel_spec.needs_norms)
+        m = A.shape[0]
+        eye_mu = jnp.eye(mu, dtype=cfg.dtype)
 
     def step(carry, h):
         alpha, x, f, dual = carry
-        idx = linalg.sample_block(jax.random.fold_in(key, h), m, mu)
-        Y = take(idx)                                    # (mu, n_loc) local
-        b_B = b[idx]
+        with phases.scope("sample"):
+            idx = linalg.sample_block(jax.random.fold_in(key, h), m, mu)
         # --- Communication: ONE fused Allreduce of [A Y^T | norms] ---
-        cross, anorms = _reduce_cross(
-            cross_block(A, densify(Y), cfg.use_pallas), axis_name,
-            norms_local)
-        Kcol = _kernelize(problem, cross, anorms, idx, cfg.dtype)
-        KBB = Kcol[idx] + gamma * eye_mu                 # (mu, mu)
-        a_B = alpha[idx]
-        g = b_B * f[idx] - 1.0 + gamma * a_B
-        # mu = 1: the (1, 1) block IS the eigenvalue — skip the power loop.
-        v = KBB[0, 0] if mu == 1 \
-            else linalg.power_iteration_max_eig(KBB, cfg.power_iters)
-        gbar = jnp.abs(jnp.clip(a_B - g, 0.0, nu) - a_B)
-        theta = jnp.where(
-            gbar != 0.0,
-            jnp.clip(a_B - g / v, 0.0, nu) - a_B,
-            0.0)
-        alpha = alpha.at[idx].add(theta)
-        bt = b_B * theta
-        f = f + Kcol @ bt                                # replicated, local
-        x = x + apply_t(Y, bt)                           # primal shadow
-        dual = dual + jnp.sum(theta * g) + 0.5 * bt @ (KBB @ bt)
-        obj = dual if cfg.track_objective else jnp.asarray(0.0, cfg.dtype)
+        with phases.scope("assemble"):
+            Y = take(idx)                                # (mu, n_loc) local
+            b_B = b[idx]
+            with phases.scope("gram"):
+                local = cross_block(A, densify(Y), cfg.use_pallas)
+        with phases.scope("reduce"):
+            cross, anorms = _reduce_cross(local, axis_name, norms_local)
+            Kcol = _kernelize(problem, cross, anorms, idx, cfg.dtype)
+            KBB = Kcol[idx] + gamma * eye_mu             # (mu, mu)
+        with phases.scope("inner"):
+            a_B = alpha[idx]
+            g = b_B * f[idx] - 1.0 + gamma * a_B
+            # mu = 1: the (1, 1) block IS the eigenvalue — skip the power
+            # loop.
+            v = KBB[0, 0] if mu == 1 \
+                else linalg.power_iteration_max_eig(KBB, cfg.power_iters)
+            gbar = jnp.abs(jnp.clip(a_B - g, 0.0, nu) - a_B)
+            theta = jnp.where(
+                gbar != 0.0,
+                jnp.clip(a_B - g / v, 0.0, nu) - a_B,
+                0.0)
+            alpha = alpha.at[idx].add(theta)
+        with phases.scope("defer"):
+            bt = b_B * theta
+            f = f + Kcol @ bt                            # replicated, local
+            x = x + apply_t(Y, bt)                       # primal shadow
+            dual = dual + jnp.sum(theta * g) + 0.5 * bt @ (KBB @ bt)
+            obj = dual if cfg.track_objective \
+                else jnp.asarray(0.0, cfg.dtype)
         return (alpha, x, f, dual), obj
 
     (alpha, x, f, dual), objs = jax.lax.scan(
         step, (alpha, x, f, dual0),
         jnp.arange(start + 1, start + cfg.iterations + 1))
-    return SolverResult(x=x, objective=objs,
-                        aux={"alpha": alpha, "dual": dual, "f": f,
-                             "state": SolveState(
-                                 start + cfg.iterations,
-                                 {"alpha": alpha, "x": x, "f": f,
-                                  "dual": dual}),
-                             **spmm_aux(A, cfg, "cross")})
+    with phases.scope("finalize"):
+        return SolverResult(
+            x=x, objective=objs,
+            aux={"alpha": alpha, "dual": dual, "f": f,
+                 "state": SolveState(start + cfg.iterations,
+                                     {"alpha": alpha, "x": x, "f": f,
+                                      "dual": dual}),
+                 **spmm_aux(A, cfg, "cross")})
 
 
 def _sak_setup(problem, cfg, axis_name, alpha0, carry0):
@@ -254,9 +263,10 @@ def _sak_assemble(ctx, carry, idxs, s_grp):
     Y = ctx.take(flat)                                # (s_grp*mu, n_loc)
     # LOCAL half of the fused [A Y^T | norms] cross block — the norms
     # column rides along only when the kernel needs it (rbf).
-    local = cross_block(ctx.A, ctx.densify(Y), ctx.cfg.use_pallas)
-    if ctx.norms_local is not None:
-        local = jnp.concatenate([local, ctx.norms_local], axis=1)
+    with phases.scope("gram"):
+        local = cross_block(ctx.A, ctx.densify(Y), ctx.cfg.use_pallas)
+        if ctx.norms_local is not None:
+            local = jnp.concatenate([local, ctx.norms_local], axis=1)
     return Y, local
 
 

@@ -22,7 +22,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.core import cost_model, linalg, prox as prox_lib
+from repro.core import cost_model, linalg, phases, prox as prox_lib
 from repro.core.sparse_exec import col_block_ops, prep_operand, spmm_aux
 from repro.core.types import (LassoProblem, SolveState, SolverConfig,
                               SolverResult, SparseOperand, operand_matvec,
@@ -105,45 +105,54 @@ def bcd_lasso(problem: LassoProblem, cfg: SolverConfig,
     residual, plus the global iteration offset) — the resumed solve
     continues the uninterrupted iterate sequence exactly.
     """
-    A, b, n, mu, q, sampler, prox = _prep(problem, cfg)
-    block_gram, block_apply = col_block_ops(A, cfg)
-    key = jax.random.key(cfg.seed)
-    carry0 = resume_carry(state, x0, "bcd_lasso")
-    start = 0 if state is None else int(state.iteration)
+    with phases.scope("setup"):
+        A, b, n, mu, q, sampler, prox = _prep(problem, cfg)
+        block_gram, block_apply = col_block_ops(A, cfg)
+        key = jax.random.key(cfg.seed)
+        carry0 = resume_carry(state, x0, "bcd_lasso")
+        start = 0 if state is None else int(state.iteration)
 
-    if carry0 is not None:
-        x0 = jnp.asarray(carry0["x"], cfg.dtype)
-        r0 = jnp.asarray(carry0["residual"], cfg.dtype)
-    elif x0 is None:
-        x0 = jnp.zeros((n,), cfg.dtype)
-        r0 = -b  # residual Ax - b at x = 0 (row shard)
-    else:
-        x0 = jnp.asarray(x0, cfg.dtype)
-        r0 = operand_matvec(A, x0) - b
+        if carry0 is not None:
+            x0 = jnp.asarray(carry0["x"], cfg.dtype)
+            r0 = jnp.asarray(carry0["residual"], cfg.dtype)
+        elif x0 is None:
+            x0 = jnp.zeros((n,), cfg.dtype)
+            r0 = -b  # residual Ax - b at x = 0 (row shard)
+        else:
+            x0 = jnp.asarray(x0, cfg.dtype)
+            r0 = operand_matvec(A, x0) - b
 
     def step(carry, h):
         x, r = carry
-        idx = sampler(jax.random.fold_in(key, h))
+        with phases.scope("sample"):
+            idx = sampler(jax.random.fold_in(key, h))
         # --- Communication: one fused Allreduce of [G | A_h^T r] ---
-        Ah, local = block_gram(idx, r[:, None])           # (mu, mu+1) local
-        GR = linalg.preduce(local, axis_name)
-        G, rh = GR[:, :mu], GR[:, mu]
-        v = linalg.power_iteration_max_eig(G, cfg.power_iters)
-        eta = 1.0 / linalg.floor_eig(v)   # floored: zero block -> no-op
-        g = x[idx] - eta * rh
-        dx = prox(g, eta) - x[idx]
-        x = x.at[idx].add(dx)
-        r = r + block_apply(Ah, dx)
-        obj = _objective(r, x, problem, axis_name) if cfg.track_objective else 0.0
+        with phases.scope("assemble"):
+            Ah, local = block_gram(idx, r[:, None])       # (mu, mu+1) local
+        with phases.scope("reduce"):
+            GR = linalg.preduce(local, axis_name)
+            G, rh = GR[:, :mu], GR[:, mu]
+        with phases.scope("inner"):
+            v = linalg.power_iteration_max_eig(G, cfg.power_iters)
+            eta = 1.0 / linalg.floor_eig(v)  # floored: zero block -> no-op
+            g = x[idx] - eta * rh
+            dx = prox(g, eta) - x[idx]
+            x = x.at[idx].add(dx)
+        with phases.scope("defer"):
+            r = r + block_apply(Ah, dx)
+            obj = _objective(r, x, problem, axis_name) \
+                if cfg.track_objective else 0.0
         return (x, r), obj
 
     (x, r), objs = jax.lax.scan(
         step, (x0, r0), jnp.arange(start + 1, start + cfg.iterations + 1))
-    return SolverResult(x=x, objective=objs,
-                        aux={"residual": r,
-                             "state": SolveState(start + cfg.iterations,
-                                                 {"x": x, "residual": r}),
-                             **spmm_aux(A, cfg, "col_gram", extra=1)})
+    with phases.scope("finalize"):
+        return SolverResult(
+            x=x, objective=objs,
+            aux={"residual": r,
+                 "state": SolveState(start + cfg.iterations,
+                                     {"x": x, "residual": r}),
+                 **spmm_aux(A, cfg, "col_gram", extra=1)})
 
 
 # ---------------------------------------------------------------------------
@@ -165,70 +174,78 @@ def acc_bcd_lasso(problem: LassoProblem, cfg: SolverConfig,
     (the schedule is a deterministic recurrence, so recomputing it over
     ``start + H`` steps reproduces the uninterrupted prefix bitwise).
     """
-    A, b, n, mu, q, sampler, prox = _prep(problem, cfg)
-    block_gram, block_apply = col_block_ops(A, cfg)
-    key = jax.random.key(cfg.seed)
-    H = cfg.iterations
-    carry0 = resume_carry(state, x0, "acc_bcd_lasso")
-    start = 0 if state is None else int(state.iteration)
+    with phases.scope("setup"):
+        A, b, n, mu, q, sampler, prox = _prep(problem, cfg)
+        block_gram, block_apply = col_block_ops(A, cfg)
+        key = jax.random.key(cfg.seed)
+        H = cfg.iterations
+        carry0 = resume_carry(state, x0, "acc_bcd_lasso")
+        start = 0 if state is None else int(state.iteration)
 
-    theta0 = jnp.asarray(mu / n, cfg.dtype)
-    thetas = linalg.theta_schedule(theta0, start + H, q)  # (start+H+1,)
+        theta0 = jnp.asarray(mu / n, cfg.dtype)
+        thetas = linalg.theta_schedule(theta0, start + H, q)  # (start+H+1,)
 
-    if carry0 is not None:
-        z0 = jnp.asarray(carry0["z"], cfg.dtype)
-        y0 = jnp.asarray(carry0["y"], cfg.dtype)
-        ztil0 = jnp.asarray(carry0["ztil"], cfg.dtype)
-        ytil0 = jnp.asarray(carry0["ytil"], cfg.dtype)
-    else:
-        if x0 is None:
-            z0 = jnp.zeros((n,), cfg.dtype)
-            ztil0 = -b                                    # A z0 - b
+        if carry0 is not None:
+            z0 = jnp.asarray(carry0["z"], cfg.dtype)
+            y0 = jnp.asarray(carry0["y"], cfg.dtype)
+            ztil0 = jnp.asarray(carry0["ztil"], cfg.dtype)
+            ytil0 = jnp.asarray(carry0["ytil"], cfg.dtype)
         else:
-            z0 = jnp.asarray(x0, cfg.dtype)
-            ztil0 = operand_matvec(A, z0) - b
-        y0 = jnp.zeros((n,), cfg.dtype)
-        ytil0 = jnp.zeros_like(b)                         # A y0
+            if x0 is None:
+                z0 = jnp.zeros((n,), cfg.dtype)
+                ztil0 = -b                                # A z0 - b
+            else:
+                z0 = jnp.asarray(x0, cfg.dtype)
+                ztil0 = operand_matvec(A, z0) - b
+            y0 = jnp.zeros((n,), cfg.dtype)
+            ytil0 = jnp.zeros_like(b)                     # A y0
 
     def step(carry, inputs):
         z, y, ztil, ytil = carry
         h, th_prev, th_cur = inputs
-        idx = sampler(jax.random.fold_in(key, h))
-        w = th_prev * th_prev * ytil + ztil               # (m_loc,)
+        with phases.scope("sample"):
+            idx = sampler(jax.random.fold_in(key, h))
         # --- Communication: one fused Allreduce of [G | r_h]  (lines 8-9) ---
-        Ah, local = block_gram(idx, w[:, None])           # (mu, mu+1) local
-        GR = linalg.preduce(local, axis_name)
-        G, rh = GR[:, :mu], GR[:, mu]
-        v = linalg.power_iteration_max_eig(G, cfg.power_iters)   # line 10
-        eta = 1.0 / linalg.floor_eig(q * th_prev * v)     # line 11 (floored)
-        g = z[idx] - eta * rh                             # line 12
-        dz = prox(g, eta) - z[idx]                        # line 13
-        z = z.at[idx].add(dz)                             # line 14
-        Adz = block_apply(Ah, dz)                         # A_h dz (local)
-        ztil = ztil + Adz                                 # line 15
-        coef = (1.0 - q * th_prev) / (th_prev * th_prev)
-        y = y.at[idx].add(-coef * dz)                     # line 16
-        ytil = ytil - coef * Adz                          # line 17
-        if cfg.track_objective:
-            res = th_cur * th_cur * ytil + ztil           # A x_h - b
-            x_h = th_cur * th_cur * y + z
-            obj = _objective(res, x_h, problem, axis_name)
-        else:
-            obj = jnp.asarray(0.0, cfg.dtype)
+        with phases.scope("assemble"):
+            w = th_prev * th_prev * ytil + ztil           # (m_loc,)
+            Ah, local = block_gram(idx, w[:, None])       # (mu, mu+1) local
+        with phases.scope("reduce"):
+            GR = linalg.preduce(local, axis_name)
+            G, rh = GR[:, :mu], GR[:, mu]
+        with phases.scope("inner"):
+            v = linalg.power_iteration_max_eig(G, cfg.power_iters)  # line 10
+            eta = 1.0 / linalg.floor_eig(q * th_prev * v)  # line 11 (floored)
+            g = z[idx] - eta * rh                         # line 12
+            dz = prox(g, eta) - z[idx]                    # line 13
+            z = z.at[idx].add(dz)                         # line 14
+        with phases.scope("defer"):
+            Adz = block_apply(Ah, dz)                     # A_h dz (local)
+            ztil = ztil + Adz                             # line 15
+            coef = (1.0 - q * th_prev) / (th_prev * th_prev)
+            y = y.at[idx].add(-coef * dz)                 # line 16
+            ytil = ytil - coef * Adz                      # line 17
+            if cfg.track_objective:
+                res = th_cur * th_cur * ytil + ztil       # A x_h - b
+                x_h = th_cur * th_cur * y + z
+                obj = _objective(res, x_h, problem, axis_name)
+            else:
+                obj = jnp.asarray(0.0, cfg.dtype)
         return (z, y, ztil, ytil), obj
 
     hs = jnp.arange(start + 1, start + H + 1)
     (z, y, ztil, ytil), objs = jax.lax.scan(
         step, (z0, y0, ztil0, ytil0), (hs, thetas[start:-1],
                                        thetas[start + 1:]))
-    thH = thetas[-1]
-    x = thH * thH * y + z                                 # line 19
-    return SolverResult(x=x, objective=objs,
-                        aux={"residual": thH * thH * ytil + ztil,
-                             "state": SolveState(
-                                 start + H, {"z": z, "y": y,
-                                             "ztil": ztil, "ytil": ytil}),
-                             **spmm_aux(A, cfg, "col_gram", extra=1)})
+    with phases.scope("finalize"):
+        thH = thetas[-1]
+        x = thH * thH * y + z                             # line 19
+        return SolverResult(
+            x=x, objective=objs,
+            aux={"residual": thH * thH * ytil + ztil,
+                 "state": SolveState(start + H, {"z": z, "y": y,
+                                                 "ztil": ztil,
+                                                 "ytil": ytil}),
+                 **spmm_aux(A, cfg, "col_gram", extra=1)})
 
 
 def cd_lasso(problem: LassoProblem, cfg: SolverConfig,

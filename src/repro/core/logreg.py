@@ -47,7 +47,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro.core import cost_model, linalg
+from repro.core import cost_model, linalg, phases
 from repro.core.sparse_exec import (cross_block, prep_operand,
                                     row_block_ops, spmm_aux)
 from repro.core.types import (LogRegProblem, SolveState, SolverConfig,
@@ -113,43 +113,51 @@ def bcd_logreg(problem: LogRegProblem, cfg: SolverConfig,
                x0=None, state: Optional[SolveState] = None) -> SolverResult:
     """Classical (synchronous) block CD / mini-batch logistic regression:
     ONE fused Allreduce of the (m, mu) cross block per iteration."""
-    mu = cfg.block_size
-    lam = jnp.asarray(problem.lam, cfg.dtype)
-    key = jax.random.key(cfg.seed)
-    carry0 = resume_carry(state, x0, "bcd_logreg")
-    start = 0 if state is None else int(state.iteration)
-    A, b, w, f, sq = _init_state(problem, cfg, axis_name, x0, carry0)
-    take, _, densify, apply_t = row_block_ops(A, cfg)
-    m = A.shape[0]
+    with phases.scope("setup"):
+        mu = cfg.block_size
+        lam = jnp.asarray(problem.lam, cfg.dtype)
+        key = jax.random.key(cfg.seed)
+        carry0 = resume_carry(state, x0, "bcd_logreg")
+        start = 0 if state is None else int(state.iteration)
+        A, b, w, f, sq = _init_state(problem, cfg, axis_name, x0, carry0)
+        take, _, densify, apply_t = row_block_ops(A, cfg)
+        m = A.shape[0]
 
     def step(carry, h):
         w, f, sq = carry
-        idx = linalg.sample_block(jax.random.fold_in(key, h), m, mu)
-        Y = take(idx)                                    # (mu, n_loc) local
+        with phases.scope("sample"):
+            idx = linalg.sample_block(jax.random.fold_in(key, h), m, mu)
         # --- Communication: ONE fused Allreduce of  A Y^T ---
-        cross = linalg.preduce(
-            cross_block(A, densify(Y), cfg.use_pallas), axis_name)  # (m, mu)
-        G = cross[idx]                                   # (mu, mu) = Y Y^T
-        fB = f[idx]                                      # = Y w (gather)
-        c = -b[idx] * jax.nn.sigmoid(-b[idx] * fB)
-        eta = _step_size(G, mu, lam, cfg.power_iters)
-        d = 1.0 - eta * lam
-        u = -(eta / mu) * c                              # (mu,)
-        w = d * w + apply_t(Y, u)                        # local shard
-        sq = d * d * sq + 2.0 * d * (fB @ u) + u @ (G @ u)
-        f = d * f + cross @ u                            # replicated
-        obj = _tracked_objective(f, sq, b, lam) if cfg.track_objective \
-            else jnp.asarray(0.0, cfg.dtype)
+        with phases.scope("assemble"):
+            Y = take(idx)                                # (mu, n_loc) local
+            with phases.scope("gram"):
+                local = cross_block(A, densify(Y), cfg.use_pallas)
+        with phases.scope("reduce"):
+            cross = linalg.preduce(local, axis_name)     # (m, mu)
+        with phases.scope("inner"):
+            G = cross[idx]                               # (mu, mu) = Y Y^T
+            fB = f[idx]                                  # = Y w (gather)
+            c = -b[idx] * jax.nn.sigmoid(-b[idx] * fB)
+            eta = _step_size(G, mu, lam, cfg.power_iters)
+            d = 1.0 - eta * lam
+            u = -(eta / mu) * c                          # (mu,)
+        with phases.scope("defer"):
+            w = d * w + apply_t(Y, u)                    # local shard
+            sq = d * d * sq + 2.0 * d * (fB @ u) + u @ (G @ u)
+            f = d * f + cross @ u                        # replicated
+            obj = _tracked_objective(f, sq, b, lam) if cfg.track_objective \
+                else jnp.asarray(0.0, cfg.dtype)
         return (w, f, sq), obj
 
     (w, f, sq), objs = jax.lax.scan(
         step, (w, f, sq), jnp.arange(start + 1, start + cfg.iterations + 1))
-    return SolverResult(x=w, objective=objs,
-                        aux={"margins": f, "w_norm_sq": sq,
-                             "state": SolveState(
-                                 start + cfg.iterations,
-                                 {"w": w, "margins": f, "sq": sq}),
-                             **spmm_aux(A, cfg, "cross")})
+    with phases.scope("finalize"):
+        return SolverResult(
+            x=w, objective=objs,
+            aux={"margins": f, "w_norm_sq": sq,
+                 "state": SolveState(start + cfg.iterations,
+                                     {"w": w, "margins": f, "sq": sq}),
+                 **spmm_aux(A, cfg, "cross")})
 
 
 def _cli_problem(args):

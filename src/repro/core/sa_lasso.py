@@ -29,7 +29,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro.core import linalg
+from repro.core import linalg, phases
 # Compatibility aliases: these helpers moved into the engine.
 from repro.core.engine import (Ctx, FamilyProgram, deferred_steps,
                                gram_and_proj as _gram_and_proj,
@@ -64,7 +64,8 @@ def _lasso_assemble(ctx, vecs, idxs, s_grp):
     flat = idxs.reshape(s_grp * ctx.mu)
     if ctx.sparse:
         return ctx.block_gram(flat, vecs)
-    Y = ctx.A[:, flat]                                # (m_loc, s*mu) local
+    with phases.scope("gather"):
+        Y = ctx.A[:, flat]                            # (m_loc, s*mu) local
     return Y, gram_local(Y, vecs, ctx.cfg.use_pallas)
 
 

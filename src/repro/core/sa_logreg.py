@@ -29,7 +29,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro.core import linalg
+from repro.core import linalg, phases
 from repro.core.engine import Ctx, FamilyProgram, run_program
 from repro.core.logreg import _init_state, _step_size, _tracked_objective
 from repro.core.sparse_exec import cross_block, row_block_ops
@@ -50,7 +50,8 @@ def _logreg_setup(problem, cfg, axis_name, x0, carry0):
 def _logreg_assemble(ctx, carry, idxs, s_grp):
     flat = idxs.reshape(s_grp * ctx.mu)
     Y = ctx.take(flat)                                # (s_grp*mu, n_loc)
-    return Y, cross_block(ctx.A, ctx.densify(Y), ctx.cfg.use_pallas)
+    with phases.scope("gram"):
+        return Y, cross_block(ctx.A, ctx.densify(Y), ctx.cfg.use_pallas)
 
 
 def _logreg_inner(ctx, carry, Y, cross, idxs, win, s_grp):

@@ -54,7 +54,7 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 
-from repro.core import cost_model, linalg, prox as prox_lib
+from repro.core import cost_model, linalg, phases, prox as prox_lib
 from repro.core.engine import (Ctx, FamilyProgram, deferred_steps,
                                gram_local, reduce_gram_proj, run_program)
 from repro.core.sparse_exec import col_block_ops, prep_operand, spmm_aux
@@ -131,46 +131,53 @@ def sfista(problem: SFISTAProblem, cfg: SolverConfig,
     is deterministic, so recomputing over ``start + H`` steps reproduces
     the uninterrupted prefix bitwise).
     """
-    A, b, n, mu, prox = _prep(problem, cfg)
-    block_gram, block_apply = col_block_ops(A, cfg)
-    key = jax.random.key(cfg.seed)
-    H = cfg.iterations
-    carry0 = resume_carry(state, x0, "sfista")
-    start = 0 if state is None else int(state.iteration)
-    ts = linalg.fista_t_schedule(start + H, cfg.dtype)    # (start+H+1,)
-    x0_, y0, rx0, ry0 = _init_iterates(A, b, n, cfg, x0, carry0)
+    with phases.scope("setup"):
+        A, b, n, mu, prox = _prep(problem, cfg)
+        block_gram, block_apply = col_block_ops(A, cfg)
+        key = jax.random.key(cfg.seed)
+        H = cfg.iterations
+        carry0 = resume_carry(state, x0, "sfista")
+        start = 0 if state is None else int(state.iteration)
+        ts = linalg.fista_t_schedule(start + H, cfg.dtype)  # (start+H+1,)
+        x0_, y0, rx0, ry0 = _init_iterates(A, b, n, cfg, x0, carry0)
 
     def step(carry, inputs):
         x, y, rx, ry = carry
         h, t_prev, t_cur = inputs
-        idx = linalg.sample_block(jax.random.fold_in(key, h), n, mu)
+        with phases.scope("sample"):
+            idx = linalg.sample_block(jax.random.fold_in(key, h), n, mu)
         # --- Communication: one fused Allreduce of [G | A_B^T ry] ---
-        Ah, local = block_gram(idx, ry[:, None])          # (mu, mu+1) local
-        GR = linalg.preduce(local, axis_name)
-        G, g = GR[:, :mu], GR[:, mu]
-        v = linalg.power_iteration_max_eig(G, cfg.power_iters)
-        eta = 1.0 / linalg.floor_eig(v)   # floored: zero block -> no-op
-        yB = y[idx]
-        d = prox(yB - eta * g, eta) - yB
-        x_new = y.at[idx].add(d)                          # prox step on y
-        rx_new = ry + block_apply(Ah, d)                  # A x_new - b
-        beta = (t_prev - 1.0) / t_cur
-        w = yB + d - x[idx]                               # x_B^h - x_B^{h-1}
-        y_new = x_new.at[idx].add(beta * w)               # subspace momentum
-        ry_new = ry + block_apply(Ah, d + beta * w)
-        obj = _objective(rx_new, x_new, problem, axis_name) \
-            if cfg.track_objective else jnp.asarray(0.0, cfg.dtype)
+        with phases.scope("assemble"):
+            Ah, local = block_gram(idx, ry[:, None])      # (mu, mu+1) local
+        with phases.scope("reduce"):
+            GR = linalg.preduce(local, axis_name)
+            G, g = GR[:, :mu], GR[:, mu]
+        with phases.scope("inner"):
+            v = linalg.power_iteration_max_eig(G, cfg.power_iters)
+            eta = 1.0 / linalg.floor_eig(v)  # floored: zero block -> no-op
+            yB = y[idx]
+            d = prox(yB - eta * g, eta) - yB
+            x_new = y.at[idx].add(d)                      # prox step on y
+        with phases.scope("defer"):
+            rx_new = ry + block_apply(Ah, d)              # A x_new - b
+            beta = (t_prev - 1.0) / t_cur
+            w = yB + d - x[idx]                           # x_B^h - x_B^{h-1}
+            y_new = x_new.at[idx].add(beta * w)           # subspace momentum
+            ry_new = ry + block_apply(Ah, d + beta * w)
+            obj = _objective(rx_new, x_new, problem, axis_name) \
+                if cfg.track_objective else jnp.asarray(0.0, cfg.dtype)
         return (x_new, y_new, rx_new, ry_new), obj
 
     hs = jnp.arange(start + 1, start + H + 1)
     (x, y, rx, ry), objs = jax.lax.scan(
         step, (x0_, y0, rx0, ry0), (hs, ts[start:-1], ts[start + 1:]))
-    return SolverResult(x=x, objective=objs,
-                        aux={"residual": rx,
-                             "state": SolveState(
-                                 start + H,
-                                 {"x": x, "y": y, "rx": rx, "ry": ry}),
-                             **spmm_aux(A, cfg, "col_gram", extra=1)})
+    with phases.scope("finalize"):
+        return SolverResult(
+            x=x, objective=objs,
+            aux={"residual": rx,
+                 "state": SolveState(start + H,
+                                     {"x": x, "y": y, "rx": rx, "ry": ry}),
+                 **spmm_aux(A, cfg, "col_gram", extra=1)})
 
 
 # ---------------------------------------------------------------------------
@@ -200,7 +207,8 @@ def _ca_assemble(ctx, carry, idxs, s_grp):
     flat = idxs.reshape(s_grp * ctx.mu)
     if ctx.sparse:
         return ctx.block_gram(flat, ry[:, None])
-    Y = ctx.A[:, flat]                                # (m_loc, s*mu) local
+    with phases.scope("gather"):
+        Y = ctx.A[:, flat]                            # (m_loc, s*mu) local
     return Y, gram_local(Y, ry[:, None], ctx.cfg.use_pallas)
 
 

@@ -20,12 +20,15 @@ closures execute only nnz work via ``repro.kernels.spmm``:
 
 All local (pre-Allreduce) quantities; communication stays in the
 solvers. ``use_pallas`` routes the SpMM through the blocked-ELL Pallas
-kernel (``repro.kernels.spmm``), subject to its VMEM guard.
+kernel (``repro.kernels.spmm``), subject to its VMEM guard. A take
+runs in the ``gather`` phase scope, a product and the building of its
+operands in the ``gram`` scope (:mod:`repro.core.phases`).
 """
 from __future__ import annotations
 
 import jax.numpy as jnp
 
+from repro.core import phases
 from repro.core.types import SparseOperand
 from repro.kernels import spmm
 
@@ -49,14 +52,16 @@ def col_block_ops(A, cfg):
         m_loc = A.shape[0]
 
         def block_gram(idx, vecs):
-            handle = A.gather_cols(idx)
-            rows, vals, nnb = handle
-            Yd = spmm.scatter_dense(rows, vals, m_loc)
-            local = spmm.ell_spmm(vals, rows, nnb,
-                                  jnp.concatenate([Yd, vecs], axis=1),
-                                  ell_block=A.ell_block,
-                                  use_pallas=cfg.use_pallas)
-            return handle, local.astype(A.dtype)
+            with phases.scope("gather"):
+                handle = A.gather_cols(idx)
+            with phases.scope("gram"):
+                rows, vals, nnb = handle
+                Yd = spmm.scatter_dense(rows, vals, m_loc)
+                local = spmm.ell_spmm(vals, rows, nnb,
+                                      jnp.concatenate([Yd, vecs], axis=1),
+                                      ell_block=A.ell_block,
+                                      use_pallas=cfg.use_pallas)
+                return handle, local.astype(A.dtype)
 
         def block_apply(handle, coef):
             rows, vals, _ = handle
@@ -66,8 +71,10 @@ def col_block_ops(A, cfg):
         return block_gram, block_apply
 
     def block_gram(idx, vecs):
-        Ah = A[:, idx]
-        return Ah, Ah.T @ jnp.concatenate([Ah, vecs], axis=1)
+        with phases.scope("gather"):
+            Ah = A[:, idx]
+        with phases.scope("gram"):
+            return Ah, Ah.T @ jnp.concatenate([Ah, vecs], axis=1)
 
     def block_apply(Ah, coef):
         return Ah @ coef
@@ -89,20 +96,23 @@ def row_block_ops(A, cfg):
         n_loc = A.shape[1]
 
         def take(idx):
-            return A.gather_rows(idx)
+            with phases.scope("gather"):
+                return A.gather_rows(idx)
 
         def gram(handle, vecs):
-            cols, vals, nnb = handle
-            local = spmm.ell_spmm(
-                vals, cols, nnb,
-                jnp.concatenate([spmm.scatter_dense(cols, vals, n_loc),
-                                 vecs], axis=1),
-                ell_block=A.ell_block, use_pallas=cfg.use_pallas)
-            return local.astype(A.dtype)
+            with phases.scope("gram"):
+                cols, vals, nnb = handle
+                local = spmm.ell_spmm(
+                    vals, cols, nnb,
+                    jnp.concatenate([spmm.scatter_dense(cols, vals, n_loc),
+                                     vecs], axis=1),
+                    ell_block=A.ell_block, use_pallas=cfg.use_pallas)
+                return local.astype(A.dtype)
 
         def densify(handle):
-            cols, vals, _ = handle
-            return spmm.scatter_dense(cols, vals, n_loc)
+            with phases.scope("gram"):
+                cols, vals, _ = handle
+                return spmm.scatter_dense(cols, vals, n_loc)
 
         def apply_t(handle, coef):
             cols, vals, _ = handle
@@ -112,13 +122,16 @@ def row_block_ops(A, cfg):
         return take, gram, densify, apply_t
 
     def take(idx):
-        return A[idx]
+        with phases.scope("gather"):
+            return A[idx]
 
     def gram(Y, vecs):
-        return Y @ jnp.concatenate([Y.T, vecs], axis=1)
+        with phases.scope("gram"):
+            return Y @ jnp.concatenate([Y.T, vecs], axis=1)
 
     def densify(Y):
-        return Y.T
+        with phases.scope("gram"):
+            return Y.T
 
     def apply_t(Y, coef):
         return Y.T @ coef

@@ -29,7 +29,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro.core import cost_model, linalg
+from repro.core import cost_model, linalg, phases
 from repro.core.sparse_exec import prep_operand, row_block_ops, spmm_aux
 from repro.core.types import (SVMProblem, SolveState, SolverConfig,
                               SolverResult, operand_matvec, operand_rmatvec,
@@ -89,73 +89,83 @@ def bdcd_svm(problem: SVMProblem, cfg: SolverConfig,
         delta f_D = theta^T g_B + 1/2 (b_B theta)^T G (b_B theta)
     where G = Y Y^T + gamma I is the reduced block the step already holds.
     """
-    A = prep_operand(problem.A, cfg.dtype)
-    take, gram, _, apply_t = row_block_ops(A, cfg)
-    b = jnp.asarray(problem.b, cfg.dtype)
-    m = A.shape[0]
-    mu = cfg.block_size
-    gamma = jnp.asarray(problem.gamma, cfg.dtype)
-    nu = jnp.asarray(problem.nu, cfg.dtype)
-    key = jax.random.key(cfg.seed)
-    carry0 = resume_carry(state, alpha0, "bdcd_svm")
-    start = 0 if state is None else int(state.iteration)
+    with phases.scope("setup"):
+        A = prep_operand(problem.A, cfg.dtype)
+        take, gram, _, apply_t = row_block_ops(A, cfg)
+        b = jnp.asarray(problem.b, cfg.dtype)
+        m = A.shape[0]
+        mu = cfg.block_size
+        gamma = jnp.asarray(problem.gamma, cfg.dtype)
+        nu = jnp.asarray(problem.nu, cfg.dtype)
+        key = jax.random.key(cfg.seed)
+        carry0 = resume_carry(state, alpha0, "bdcd_svm")
+        start = 0 if state is None else int(state.iteration)
 
-    if carry0 is not None:
-        # resume: alpha, the primal shard x AND the running dual come
-        # back from the checkpoint — no matvec, no Allreduce, so the
-        # resumed sequence is bit-identical to the uninterrupted one.
-        alpha = jnp.asarray(carry0["alpha"], cfg.dtype)
-        x = jnp.asarray(carry0["x"], cfg.dtype)
-        dual0 = jnp.asarray(carry0["dual"], cfg.dtype)
-    else:
-        alpha = jnp.zeros((m,), cfg.dtype) if alpha0 is None \
-            else jnp.asarray(alpha0, cfg.dtype)
-        x = operand_rmatvec(A, b * alpha)                # line 2 (local shard)
-        # incremental tracking resumes from f_D(alpha0) on warm start (zero
-        # at alpha0 = 0 without any communication), so a warm-started
-        # solve's objective trace continues the previous solve's. Reuses
-        # the x we just built:
-        # f_D(alpha) = 1/2 ||A^T(b a)||^2 + gamma/2 ||a||^2 - e^T a.
-        dual0 = jnp.asarray(0.0, cfg.dtype) if alpha0 is None else (
-            0.5 * linalg.preduce(jnp.sum(x * x), axis_name)
-            + 0.5 * gamma * jnp.sum(alpha * alpha) - jnp.sum(alpha))
-    eye_mu = jnp.eye(mu, dtype=cfg.dtype)
+        if carry0 is not None:
+            # resume: alpha, the primal shard x AND the running dual come
+            # back from the checkpoint — no matvec, no Allreduce, so the
+            # resumed sequence is bit-identical to the uninterrupted one.
+            alpha = jnp.asarray(carry0["alpha"], cfg.dtype)
+            x = jnp.asarray(carry0["x"], cfg.dtype)
+            dual0 = jnp.asarray(carry0["dual"], cfg.dtype)
+        else:
+            alpha = jnp.zeros((m,), cfg.dtype) if alpha0 is None \
+                else jnp.asarray(alpha0, cfg.dtype)
+            x = operand_rmatvec(A, b * alpha)            # line 2 (local shard)
+            # incremental tracking resumes from f_D(alpha0) on warm start
+            # (zero at alpha0 = 0 without any communication), so a
+            # warm-started solve's objective trace continues the previous
+            # solve's. Reuses the x we just built:
+            # f_D(alpha) = 1/2 ||A^T(b a)||^2 + gamma/2 ||a||^2 - e^T a.
+            dual0 = jnp.asarray(0.0, cfg.dtype) if alpha0 is None else (
+                0.5 * linalg.preduce(jnp.sum(x * x), axis_name)
+                + 0.5 * gamma * jnp.sum(alpha * alpha) - jnp.sum(alpha))
+        eye_mu = jnp.eye(mu, dtype=cfg.dtype)
 
     def step(carry, h):
         alpha, x, dual = carry
-        idx = linalg.sample_block(jax.random.fold_in(key, h), m, mu)
-        Y = take(idx)                                    # (mu, n_loc) local
-        b_B = b[idx]
+        with phases.scope("sample"):
+            idx = linalg.sample_block(jax.random.fold_in(key, h), m, mu)
         # --- Communication: ONE fused Allreduce of  Y [Y^T | x] ---
-        red = linalg.preduce(gram(Y, x[:, None]), axis_name)
-        G = red[:, :mu] + gamma * eye_mu                 # line 7 (block)
-        a_B = alpha[idx]
-        g = b_B * red[:, mu] - 1.0 + gamma * a_B         # line 8 (block)
-        # mu = 1: the (1, 1) Gram "block" IS the eigenvalue (paper
-        # Alg. 3's eta = ||a_i||^2 + gamma) — skip the power loop.
-        v = G[0, 0] if mu == 1 \
-            else linalg.power_iteration_max_eig(G, cfg.power_iters)
-        gbar = jnp.abs(jnp.clip(a_B - g, 0.0, nu) - a_B)             # line 9
-        theta = jnp.where(
-            gbar != 0.0,
-            jnp.clip(a_B - g / v, 0.0, nu) - a_B,                    # line 11
-            0.0)
-        alpha = alpha.at[idx].add(theta)                 # line 13
-        bt = b_B * theta
-        x = x + apply_t(Y, bt)                           # line 14 (local)
-        dual = dual + jnp.sum(theta * g) + 0.5 * bt @ (G @ bt)
-        obj = dual if cfg.track_objective else jnp.asarray(0.0, cfg.dtype)
+        with phases.scope("assemble"):
+            Y = take(idx)                                # (mu, n_loc) local
+            b_B = b[idx]
+            local = gram(Y, x[:, None])
+        with phases.scope("reduce"):
+            red = linalg.preduce(local, axis_name)
+            G = red[:, :mu] + gamma * eye_mu             # line 7 (block)
+        with phases.scope("inner"):
+            a_B = alpha[idx]
+            g = b_B * red[:, mu] - 1.0 + gamma * a_B     # line 8 (block)
+            # mu = 1: the (1, 1) Gram "block" IS the eigenvalue (paper
+            # Alg. 3's eta = ||a_i||^2 + gamma) — skip the power loop.
+            v = G[0, 0] if mu == 1 \
+                else linalg.power_iteration_max_eig(G, cfg.power_iters)
+            gbar = jnp.abs(jnp.clip(a_B - g, 0.0, nu) - a_B)         # line 9
+            theta = jnp.where(
+                gbar != 0.0,
+                jnp.clip(a_B - g / v, 0.0, nu) - a_B,                # line 11
+                0.0)
+            alpha = alpha.at[idx].add(theta)             # line 13
+        with phases.scope("defer"):
+            bt = b_B * theta
+            x = x + apply_t(Y, bt)                       # line 14 (local)
+            dual = dual + jnp.sum(theta * g) + 0.5 * bt @ (G @ bt)
+            obj = dual if cfg.track_objective \
+                else jnp.asarray(0.0, cfg.dtype)
         return (alpha, x, dual), obj
 
     (alpha, x, dual), objs = jax.lax.scan(
         step, (alpha, x, dual0),
         jnp.arange(start + 1, start + cfg.iterations + 1))
-    return SolverResult(x=x, objective=objs,
-                        aux={"alpha": alpha, "dual": dual,
-                             "state": SolveState(
-                                 start + cfg.iterations,
-                                 {"alpha": alpha, "x": x, "dual": dual}),
-                             **spmm_aux(A, cfg, "row_gram", extra=1)})
+    with phases.scope("finalize"):
+        return SolverResult(
+            x=x, objective=objs,
+            aux={"alpha": alpha, "dual": dual,
+                 "state": SolveState(start + cfg.iterations,
+                                     {"alpha": alpha, "x": x,
+                                      "dual": dual}),
+                 **spmm_aux(A, cfg, "row_gram", extra=1)})
 
 
 def dcd_svm(problem: SVMProblem, cfg: SolverConfig,

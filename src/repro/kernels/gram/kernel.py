@@ -68,4 +68,5 @@ def gram_t_pallas(x, y, *, block_m: int = 256, block_i: int = 128,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="gram_t",
     )(x, y)

@@ -102,6 +102,7 @@ def sa_inner_pallas(G, y_proj, z_proj, z_vals, idx, th_prev, coefU,
         out_shape=(jax.ShapeDtypeStruct((1, P), jnp.float32),
                    jax.ShapeDtypeStruct((1, P), jnp.float32)),
         interpret=interpret,
+        name="sa_inner",
     )(th_prev.reshape(s).astype(jnp.float32),
       idx.reshape(smu).astype(jnp.int32), Gp, blk, idx_row, coefU_row,
       yp, zp, zv, eig)

@@ -88,5 +88,6 @@ def ell_spmm_pallas(vals, idx, blocks, D, *, ell_block: int,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
+        name="spmm",
     )(tile_blocks, idx, vals, D.astype(jnp.float32))
     return out[:R]

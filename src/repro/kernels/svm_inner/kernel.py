@@ -97,6 +97,7 @@ def svm_inner_pallas(G, proj, b_sel, a_vals, idx, *, gamma: float,
         out_shape=(jax.ShapeDtypeStruct((1, P), jnp.float32),
                    jax.ShapeDtypeStruct((1, P), jnp.float32)),
         interpret=interpret,
+        name="svm_inner",
     )(idx.reshape(smu).astype(jnp.int32), Gp, blk, idx_row, pr, b, av,
       eig)
     return theta[0, :smu].reshape(s, mu), duals[0, :smu:mu]

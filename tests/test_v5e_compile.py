@@ -6,13 +6,17 @@ Interpret mode cannot see what Mosaic refuses (unaligned tiles, dynamic
 lane indexing, more VMEM than a kernel may use); these compiles can,
 without a chip. Each asserts that the compiled HLO holds the kernel
 (``tpu_custom_call``), i.e. that the wrapper did not fall back to the
-jnp reference.
+jnp reference, and that the kernel's instruction carries the kernel's
+name (``gram_t``, ``spmm``, ``sa_inner``, ``svm_inner``): the chip
+benchmark finds a kernel's trace events by that name.
 
 The topology is described inside a module-scoped fixture, never at
 import: only one process at a time may load the TPU compiler's library,
 so every pytest worker must collect the same tests and only the worker
 that runs this file may touch it.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -51,6 +55,16 @@ def _compile_text(fn, sharding, *shapes):
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
+def _assert_kernel(text, word):
+    """The compiled HLO holds Mosaic kernels, each named ``word.N``."""
+    names = [m.group(1) for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln
+             for m in [re.match(r"\s*(?:ROOT )?%([\w.\-]+) = ", ln)] if m]
+    assert names
+    assert all(re.fullmatch(re.escape(word) + r"\.\d+", n) for n in names), \
+        names
+
+
 F32, I32 = jnp.float32, jnp.int32
 
 
@@ -61,7 +75,7 @@ F32, I32 = jnp.float32, jnp.int32
 def test_gram_compiles_for_v5e(one_chip, m, c):
     text = _compile_text(lambda x, y: gram_t(x, y, use_pallas=True),
                          one_chip, ((m, c), F32), ((m, c + 1), F32))
-    assert "tpu_custom_call" in text
+    _assert_kernel(text, "gram_t")
 
 
 @pytest.mark.parametrize("R,K,C,Q", [
@@ -74,7 +88,7 @@ def test_spmm_compiles_for_v5e(one_chip, R, K, C, Q):
         lambda v, i, b, d: ell_spmm(v, i, b, d, ell_block=8,
                                     use_pallas=True),
         one_chip, ((R, K), F32), ((R, K), I32), ((R,), I32), ((C, Q), F32))
-    assert "tpu_custom_call" in text
+    _assert_kernel(text, "spmm")
 
 
 # (s, mu): the chip_smoke shape, and the largest s*mu the (s*mu)^2 * 4 B
@@ -91,7 +105,7 @@ def test_sa_inner_compiles_for_v5e(one_chip, s, mu):
             use_pallas=True),
         one_chip, ((s * mu, s * mu), F32), ((s, mu), F32), ((s, mu), F32),
         ((s, mu), F32), ((s, mu), I32), ((s,), F32), ((s,), F32))
-    assert "tpu_custom_call" in text
+    _assert_kernel(text, "sa_inner")
 
 
 @pytest.mark.parametrize("s,mu", _INNER)
@@ -102,4 +116,4 @@ def test_svm_inner_compiles_for_v5e(one_chip, s, mu):
             G, pr, b, av, idx, gamma=1e-3, nu=1.0, use_pallas=True),
         one_chip, ((s * mu, s * mu), F32), ((s, mu), F32), ((s, mu), F32),
         ((s, mu), F32), ((s, mu), I32))
-    assert "tpu_custom_call" in text
+    _assert_kernel(text, "svm_inner")
