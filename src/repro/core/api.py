@@ -210,7 +210,9 @@ def solve_sharded(problem, cfg: SolverConfig, mesh: Mesh,
     ``state_layout``) are zero-padded and re-sharded onto THIS mesh, so
     a state checkpointed on one mesh resumes on any other (the elastic
     recovery path). The returned ``aux["state"]`` is logical again:
-    partition leaves are unpadded before they leave this function.
+    partition leaves are unpadded before they leave this function. The
+    local solve's string labels (``spmm_impl``, ``gather_layout``, ...)
+    are returned as they are.
     """
     fam = resolve_family(problem, family)
     if axes is None:
@@ -261,6 +263,8 @@ def solve_sharded(problem, cfg: SolverConfig, mesh: Mesh,
                 in_specs.append(P())
             args.append(jnp.asarray(leaf, cfg.dtype))
 
+    labels = {}     # the local solve's static labels (spmm_impl, ...)
+
     def local_solve(A_loc, b_loc, *rest):
         if sparse:
             A_loc = A_loc.squeeze_shard()
@@ -273,6 +277,8 @@ def solve_sharded(problem, cfg: SolverConfig, mesh: Mesh,
                  in zip(layout, rest[n_x0:])})
         res = fam.solve(local, cfg, axis_name=axes,
                         x0=rest[0] if n_x0 else None, **kw)
+        labels.update({k: v for k, v in res.aux.items()
+                       if isinstance(v, str)})
         outs = (res.x, res.objective) \
             + tuple(res.aux[k] for k, _ in fam.aux_out)
         if layout:
@@ -296,6 +302,7 @@ def solve_sharded(problem, cfg: SolverConfig, mesh: Mesh,
     x, objective = out[0], out[1]
     n_aux = len(fam.aux_out)
     aux = {k: v for (k, _), v in zip(fam.aux_out, out[2:2 + n_aux])}
+    aux.update(labels)
     if layout:
         start = 0 if state is None else int(state.iteration)
         aux["state"] = SolveState(

@@ -51,7 +51,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import linalg, phases
-from repro.core.sparse_exec import spmm_aux
+from repro.core.sparse_exec import operand_aux
 from repro.core.types import (SolveState, SolverResult, resume_carry)
 from repro.kernels import spmm
 from repro.kernels.gram import gram_t
@@ -187,8 +187,8 @@ def deferred_steps(ctx, handle, buf, s_grp):
         return spmm.scatter_steps(rows_g.reshape(s_grp, ctx.mu, -1),
                                   vals_g.reshape(s_grp, ctx.mu, -1),
                                   buf, ctx.m_loc)
-    return jnp.einsum("msc,sc->sm",
-                      handle.reshape(ctx.m_loc, s_grp, ctx.mu), buf)
+    return jnp.einsum("scm,sc->sm",
+                      handle.reshape(s_grp, ctx.mu, ctx.m_loc), buf)
 
 
 # ---------------------------------------------------------------------------
@@ -259,8 +259,9 @@ class FamilyProgram:
         ``aux["inner_impl"]`` with main+tail labels.
     spmm_kind / spmm_extra: the sparse-execution layout of the fused
         payload ("col_gram" / "row_gram" / "cross" + appended-vector
-        count) — the engine derives ``aux["spmm_impl"]`` from it (ONE
-        place, so the label cannot drift from the dispatched shapes).
+        count) — the engine derives ``aux["spmm_impl"]`` (and, for a
+        dense "col_gram", ``aux["gather_layout"]``) from it (ONE place,
+        so the label cannot drift from the dispatched shapes).
         Requires ``ctx.A`` to be the prepared operand.
     """
 
@@ -328,6 +329,6 @@ def run_program(prog: FamilyProgram, problem, cfg, axis_name=None,
         aux["inner_impl"] = grouped_impl_label(
             inner_impl, H, s, cfg.block_size, cfg.use_pallas, itemsize)
     if prog.spmm_kind is not None:
-        aux.update(spmm_aux(ctx.A, cfg, prog.spmm_kind, H=H,
-                            extra=prog.spmm_extra))
+        aux.update(operand_aux(ctx.A, cfg, prog.spmm_kind, H=H,
+                               extra=prog.spmm_extra))
     return SolverResult(x=x, objective=objs, aux=aux)

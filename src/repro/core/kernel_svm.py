@@ -48,8 +48,8 @@ import jax.numpy as jnp
 
 from repro.core import cost_model, linalg, phases
 from repro.core.engine import Ctx, FamilyProgram, run_program
-from repro.core.sparse_exec import (cross_block, prep_operand,
-                                    row_block_ops, spmm_aux)
+from repro.core.sparse_exec import (cross_block, operand_aux,
+                                    prep_operand, row_block_ops)
 from repro.core.types import (SVMProblem, SolveState, SolverConfig,
                               SolverResult, SparseOperand, register_family,
                               resume_carry)
@@ -242,7 +242,7 @@ def kbdcd_svm(problem: SVMProblem, cfg: SolverConfig,
                  "state": SolveState(start + cfg.iterations,
                                      {"alpha": alpha, "x": x, "f": f,
                                       "dual": dual}),
-                 **spmm_aux(A, cfg, "cross")})
+                 **operand_aux(A, cfg, "cross")})
 
 
 def _sak_setup(problem, cfg, axis_name, alpha0, carry0):

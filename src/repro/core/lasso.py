@@ -23,7 +23,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import cost_model, linalg, phases, prox as prox_lib
-from repro.core.sparse_exec import col_block_ops, prep_operand, spmm_aux
+from repro.core.sparse_exec import col_block_ops, operand_aux, prep_operand
 from repro.core.types import (LassoProblem, SolveState, SolverConfig,
                               SolverResult, SparseOperand, operand_matvec,
                               register_family, require_unit_block,
@@ -152,7 +152,7 @@ def bcd_lasso(problem: LassoProblem, cfg: SolverConfig,
             aux={"residual": r,
                  "state": SolveState(start + cfg.iterations,
                                      {"x": x, "residual": r}),
-                 **spmm_aux(A, cfg, "col_gram", extra=1)})
+                 **operand_aux(A, cfg, "col_gram", extra=1)})
 
 
 # ---------------------------------------------------------------------------
@@ -245,7 +245,7 @@ def acc_bcd_lasso(problem: LassoProblem, cfg: SolverConfig,
                  "state": SolveState(start + H, {"z": z, "y": y,
                                                  "ztil": ztil,
                                                  "ytil": ytil}),
-                 **spmm_aux(A, cfg, "col_gram", extra=1)})
+                 **operand_aux(A, cfg, "col_gram", extra=1)})
 
 
 def cd_lasso(problem: LassoProblem, cfg: SolverConfig,

@@ -48,8 +48,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import cost_model, linalg, phases
-from repro.core.sparse_exec import (cross_block, prep_operand,
-                                    row_block_ops, spmm_aux)
+from repro.core.sparse_exec import (cross_block, operand_aux,
+                                    prep_operand, row_block_ops)
 from repro.core.types import (LogRegProblem, SolveState, SolverConfig,
                               SolverResult, SparseOperand, operand_matvec,
                               register_family, resume_carry)
@@ -157,7 +157,7 @@ def bcd_logreg(problem: LogRegProblem, cfg: SolverConfig,
             aux={"margins": f, "w_norm_sq": sq,
                  "state": SolveState(start + cfg.iterations,
                                      {"w": w, "margins": f, "sq": sq}),
-                 **spmm_aux(A, cfg, "cross")})
+                 **operand_aux(A, cfg, "cross")})
 
 
 def _cli_problem(args):

@@ -29,7 +29,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro.core import linalg, phases
+from repro.core import linalg
 # Compatibility aliases: these helpers moved into the engine.
 from repro.core.engine import (Ctx, FamilyProgram, deferred_steps,
                                gram_and_proj as _gram_and_proj,
@@ -38,7 +38,7 @@ from repro.core.engine import (Ctx, FamilyProgram, deferred_steps,
                                run_program,
                                sample_all as _sample_all)
 from repro.core.lasso import _objective, _prep
-from repro.core.sparse_exec import col_block_ops
+from repro.core.sparse_exec import col_block_ops, col_take
 from repro.core.types import (LassoProblem, SolveState, SolverConfig,
                               SolverResult, SparseOperand, operand_matvec,
                               require_unit_block)
@@ -46,9 +46,11 @@ from repro.core.types import (LassoProblem, SolveState, SolverConfig,
 
 def _lasso_ctx(problem, cfg, axis_name):
     A, b, n, mu, q, sampler, prox = _prep(problem, cfg)
+    sparse = isinstance(A, SparseOperand)
     return Ctx(A=A, b=b, n=n, mu=mu, q=q, sampler=sampler, prox=prox,
-               sparse=isinstance(A, SparseOperand),
-               block_gram=col_block_ops(A, cfg)[0],
+               sparse=sparse,
+               block_gram=col_block_ops(A, cfg)[0] if sparse else None,
+               take=None if sparse else col_take(A, cfg, grouped=True),
                m_loc=A.shape[0], problem=problem, cfg=cfg,
                axis_name=axis_name)
 
@@ -64,9 +66,8 @@ def _lasso_assemble(ctx, vecs, idxs, s_grp):
     flat = idxs.reshape(s_grp * ctx.mu)
     if ctx.sparse:
         return ctx.block_gram(flat, vecs)
-    with phases.scope("gather"):
-        Y = ctx.A[:, flat]                            # (m_loc, s*mu) local
-    return Y, gram_local(Y, vecs, ctx.cfg.use_pallas)
+    Yt = ctx.take(flat)                               # (s*mu, m_loc) local
+    return Yt, gram_local(Yt.T, vecs, ctx.cfg.use_pallas)
 
 
 def _lasso_reduce(ctx, local, idxs, s_grp, vec_cols):

@@ -57,7 +57,8 @@ import jax.numpy as jnp
 from repro.core import cost_model, linalg, phases, prox as prox_lib
 from repro.core.engine import (Ctx, FamilyProgram, deferred_steps,
                                gram_local, reduce_gram_proj, run_program)
-from repro.core.sparse_exec import col_block_ops, prep_operand, spmm_aux
+from repro.core.sparse_exec import (col_block_ops, col_take, operand_aux,
+                                    prep_operand)
 from repro.core.types import (SolveState, SolverConfig, SolverResult,
                               SparseOperand, operand_matvec,
                               register_family, resume_carry)
@@ -177,7 +178,7 @@ def sfista(problem: SFISTAProblem, cfg: SolverConfig,
             aux={"residual": rx,
                  "state": SolveState(start + H,
                                      {"x": x, "y": y, "rx": rx, "ry": ry}),
-                 **spmm_aux(A, cfg, "col_gram", extra=1)})
+                 **operand_aux(A, cfg, "col_gram", extra=1)})
 
 
 # ---------------------------------------------------------------------------
@@ -186,9 +187,10 @@ def sfista(problem: SFISTAProblem, cfg: SolverConfig,
 
 def _ca_setup(problem, cfg, axis_name, x0, carry0):
     A, b, n, mu, prox = _prep(problem, cfg)
-    ctx = Ctx(A=A, b=b, n=n, mu=mu, prox=prox,
-              sparse=isinstance(A, SparseOperand),
-              block_gram=col_block_ops(A, cfg)[0],
+    sparse = isinstance(A, SparseOperand)
+    ctx = Ctx(A=A, b=b, n=n, mu=mu, prox=prox, sparse=sparse,
+              block_gram=col_block_ops(A, cfg)[0] if sparse else None,
+              take=None if sparse else col_take(A, cfg, grouped=True),
               m_loc=A.shape[0], problem=problem, cfg=cfg,
               axis_name=axis_name)
     return ctx, _init_iterates(A, b, n, cfg, x0, carry0)
@@ -207,9 +209,8 @@ def _ca_assemble(ctx, carry, idxs, s_grp):
     flat = idxs.reshape(s_grp * ctx.mu)
     if ctx.sparse:
         return ctx.block_gram(flat, ry[:, None])
-    with phases.scope("gather"):
-        Y = ctx.A[:, flat]                            # (m_loc, s*mu) local
-    return Y, gram_local(Y, ry[:, None], ctx.cfg.use_pallas)
+    Yt = ctx.take(flat)                               # (s*mu, m_loc) local
+    return Yt, gram_local(Yt.T, ry[:, None], ctx.cfg.use_pallas)
 
 
 def _ca_reduce(ctx, local, idxs, s_grp):

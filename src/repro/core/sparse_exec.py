@@ -23,9 +23,16 @@ solvers. ``use_pallas`` routes the SpMM through the blocked-ELL Pallas
 kernel (``repro.kernels.spmm``), subject to its VMEM guard. A take
 runs in the ``gather`` phase scope, a product and the building of its
 operands in the ``gram`` scope (:mod:`repro.core.phases`).
+
+A sparse operand keeps both orientations (column- and row-major ELL).
+A dense one takes its second per solve: ``col_take`` gives a dense
+column-sampling solve A^T, made once in its set-up, and reads the
+sampled columns as rows of it (DESIGN.md "The dense operand's
+orientation").
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from repro.core import phases
@@ -41,8 +48,72 @@ def prep_operand(A, dtype):
     return jnp.asarray(A, dtype)
 
 
+def device_bytes_limit():
+    """The local device's memory in bytes, or None where the backend
+    reports none (the CPU)."""
+    stats = jax.local_devices()[0].memory_stats()
+    return (stats or {}).get("bytes_limit")
+
+
+def _col_gathers(cfg, grouped: bool) -> int:
+    """The column gathers a solve makes: one per outer group of the
+    s-step schedule (``grouped``), else one per iteration."""
+    return -(-cfg.iterations // cfg.s) if grouped else cfg.iterations
+
+
+def gather_layout(A, gathers: int):
+    """How a column-sampling solve that makes ``gathers`` gathers takes
+    the columns of a dense A — the ``aux["gather_layout"]`` label, None
+    for a sparse operand:
+
+      * "feature_major": rows of A^T, made once in the set-up. Making it
+        costs about one of the old gathers (each of which copies all of
+        A), so it pays from two gathers on; it may hold a second A, so
+        only where A fits in half of the device's memory (where the
+        backend reports it);
+      * "row_major": columns of A itself, at every gather.
+    """
+    if isinstance(A, SparseOperand):
+        return None
+    limit = device_bytes_limit()
+    fits = limit is None or 2 * A.size * A.dtype.itemsize <= limit
+    return "feature_major" if gathers >= 2 and fits else "row_major"
+
+
+def col_take(A, cfg, grouped: bool = False):
+    """take(idx) -> A[:, idx].T, the feature-major block (len(idx), m_loc)
+    of a dense A's sampled columns, taken as :func:`gather_layout`
+    decides; the values are A's, exactly.
+
+    Feature-major, A^T is made here (in the caller's set-up), behind an
+    optimization barrier so the compiler keeps it out of the loop's
+    takes, and a take is one row slice of it per sampled column
+    (``lax.map``): the TPU's gather splits a large operand (epsilon's
+    3.2 GB A, a 0.8 GB shard) into slices of all of it, copied at every
+    take, for ``A^T[idx]`` as for ``A[:, idx]``. A^T is (n, 1, m_loc)
+    and the taken rows are kept apart by a second barrier: with a unit
+    second-minor dimension the TPU tiles each row alone, so a row slice
+    reads and writes one contiguous run."""
+    if gather_layout(A, _col_gathers(cfg, grouped)) == "row_major":
+        def take(idx):
+            with phases.scope("gather"):
+                return A[:, idx].T
+        return take
+    At = jax.lax.optimization_barrier(A.T[:, None, :])   # (n, 1, m_loc)
+
+    def row(i):
+        return jax.lax.dynamic_index_in_dim(At, i, keepdims=False)
+
+    def take(idx):
+        with phases.scope("gather"):
+            rows = jax.lax.optimization_barrier(jax.lax.map(row, idx))
+            return rows.reshape(idx.shape[0], A.shape[0])
+    return take
+
+
 def col_block_ops(A, cfg):
-    """(block_gram, block_apply) for the column-sampling (Lasso) layout.
+    """(block_gram, block_apply) for the column-sampling (Lasso) layout
+    of a classical solve (one block per iteration).
 
     block_gram(idx, vecs) -> (handle, local) with
         local = A_B^T [A_B | vecs]   (mu, mu + k), LOCAL (pre-reduce);
@@ -70,9 +141,10 @@ def col_block_ops(A, cfg):
 
         return block_gram, block_apply
 
+    take = col_take(A, cfg)
+
     def block_gram(idx, vecs):
-        with phases.scope("gather"):
-            Ah = A[:, idx]
+        Ah = take(idx).T
         with phases.scope("gram"):
             return Ah, Ah.T @ jnp.concatenate([Ah, vecs], axis=1)
 
@@ -139,11 +211,12 @@ def row_block_ops(A, cfg):
     return take, gram, densify, apply_t
 
 
-def spmm_aux(A, cfg, kind: str, H=None, extra: int = 0) -> dict:
-    """The ``aux["spmm_impl"]`` entry for a sparse solve — empty for
-    dense operands. ONE place derives the (R, K, C, Q) SpMM shape from
-    the layout, so the surfaced label cannot drift from the shapes the
-    solver actually dispatches:
+def operand_aux(A, cfg, kind: str, H=None, extra: int = 0) -> dict:
+    """The operand's execution labels: ``aux["spmm_impl"]`` for a sparse
+    solve, ``aux["gather_layout"]`` (:func:`gather_layout`) for a dense
+    "col_gram" one, nothing else for a dense operand. ONE place derives
+    the (R, K, C, Q) SpMM shape from the layout, so the surfaced label
+    cannot drift from the shapes the solver actually dispatches:
 
       * "col_gram" — Lasso fused  A_B^T [A_B | vecs]  (columns sampled);
       * "row_gram" — SVM fused    Y [Y^T | vecs]      (rows sampled);
@@ -154,7 +227,10 @@ def spmm_aux(A, cfg, kind: str, H=None, extra: int = 0) -> dict:
     label over the SA schedule (H, cfg.s).
     """
     if not isinstance(A, SparseOperand):
-        return {}
+        if kind != "col_gram":
+            return {}
+        return {"gather_layout": gather_layout(
+            A, _col_gathers(cfg, grouped=H is not None))}
     mu = cfg.block_size
     if kind == "col_gram":
         K, C = A.col_rows.shape[1], A.shape[0]

@@ -30,7 +30,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import cost_model, linalg, phases
-from repro.core.sparse_exec import prep_operand, row_block_ops, spmm_aux
+from repro.core.sparse_exec import operand_aux, prep_operand, row_block_ops
 from repro.core.types import (SVMProblem, SolveState, SolverConfig,
                               SolverResult, operand_matvec, operand_rmatvec,
                               register_family, require_unit_block,
@@ -165,7 +165,7 @@ def bdcd_svm(problem: SVMProblem, cfg: SolverConfig,
                  "state": SolveState(start + cfg.iterations,
                                      {"alpha": alpha, "x": x,
                                       "dual": dual}),
-                 **spmm_aux(A, cfg, "row_gram", extra=1)})
+                 **operand_aux(A, cfg, "row_gram", extra=1)})
 
 
 def dcd_svm(problem: SVMProblem, cfg: SolverConfig,

@@ -60,7 +60,7 @@ def candidate_grid(fam, problem, base_cfg: SolverConfig
 def _spmm_shapes(problem, fam, s: int, mu: int,
                  accelerated: bool = True):
     """(R, K, C, Q) of every blocked-ELL SpMM a sparse solve at (s, mu)
-    dispatches — mirrors ``sparse_exec.spmm_aux``'s shape derivations,
+    dispatches — mirrors ``sparse_exec.operand_aux``'s shape derivations,
     including which ONE product each family actually issues (guarding a
     shape the solve never dispatches would wrongly withhold Pallas:
     the (m, s*mu) cross block alone exceeds the cap for large m, but

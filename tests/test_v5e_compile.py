@@ -10,6 +10,10 @@ jnp reference, and that the kernel's instruction carries the kernel's
 name (``gram_t``, ``spmm``, ``sa_inner``, ``svm_inner``): the chip
 benchmark finds a kernel's trace events by that name.
 
+Whole dense Lasso solves are compiled at epsilon's shape too, to show
+that the column gather of every iteration reads only the sampled
+columns (``repro.core.sparse_exec.col_take``).
+
 The topology is described inside a module-scoped fixture, never at
 import: only one process at a time may load the TPU compiler's library,
 so every pytest worker must collect the same tests and only the worker
@@ -19,8 +23,12 @@ import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
+from repro import api
+from repro.core import sparse_exec
+from repro.core.types import LassoProblem, SolverConfig
 from repro.kernels import dispatch
 from repro.kernels.gram import gram_t
 from repro.kernels.sa_inner import sa_inner_loop
@@ -117,3 +125,67 @@ def test_svm_inner_compiles_for_v5e(one_chip, s, mu):
         one_chip, ((s * mu, s * mu), F32), ((s, mu), F32), ((s, mu), F32),
         ((s, mu), F32), ((s, mu), I32))
     _assert_kernel(text, "svm_inner")
+
+
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) .*\{$")
+_INSTRUCTION = re.compile(r"\s*(?:ROOT )?%[\w.\-]+ = (.*?) ([a-z][\w\-]*)\(")
+_ARRAY = re.compile(r"\b[a-z]+\d*\[([\d,]*)\]")
+# instructions that hold no buffer of their own
+_VIEWS = {"parameter", "get-tuple-element", "tuple", "bitcast", "constant",
+          "while"}
+
+
+def _largest_in_loops(text):
+    """The most elements any one array result holds among the
+    instructions of the program's loop bodies, apart from the Gram's
+    operands (``phase.gram``)."""
+    comps, cur = {}, None
+    for ln in text.splitlines():
+        m = _COMPUTATION.match(ln)
+        if m:
+            cur = comps.setdefault(m.group(1), [])
+        elif cur is not None and ln.startswith("  "):
+            cur.append(ln)
+    bodies = {b for lines in comps.values() for ln in lines
+              for b in re.findall(r"body=%([\w.\-]+)", ln)}
+    largest = 0
+    for body in bodies:
+        for ln in comps[body]:
+            m = _INSTRUCTION.match(ln)
+            if not m or m.group(2) in _VIEWS or "phase.gram" in ln:
+                continue
+            for dims in _ARRAY.findall(m.group(1)):
+                largest = max(largest, int(np.prod(
+                    [int(d) for d in dims.split(",") if d])))
+    return largest
+
+
+@pytest.mark.parametrize("form", ["feature_major", "row_major"])
+@pytest.mark.parametrize("s,H,use_pallas", [
+    (16, 512, True),     # sa_bcd_lasso, as epsilon-lasso.sa16 runs it
+    (1, 128, False),     # bcd_lasso, as epsilon-lasso.s1 runs it
+])
+def test_dense_lasso_loop_gathers_only_the_block(one_chip, monkeypatch,
+                                                 form, s, H, use_pallas):
+    """No loop instruction holds more than the s mu sampled columns: A
+    is not relayouted at every gather. The row-major form (where a
+    second A would not fit: a device of one byte) shows the test bites:
+    its gather copies all of A in slices at every iteration."""
+    if form == "row_major":
+        monkeypatch.setattr(sparse_exec, "device_bytes_limit", lambda: 1)
+    m, n, mu = 400_000, 2_000, 8
+    cfg = SolverConfig(s=s, block_size=mu, iterations=H,
+                       use_pallas=use_pallas)
+    labels = {}
+
+    def run(A, b):
+        res = api.solve(LassoProblem(A=A, b=b, lam=0.1), cfg)
+        labels.update(res.aux)
+        return res.x, res.objective
+
+    text = _compile_text(run, one_chip, ((m, n), F32), ((m,), F32))
+    assert labels["gather_layout"] == form
+    if form == "feature_major":
+        assert _largest_in_loops(text) <= s * mu * m
+    else:
+        assert _largest_in_loops(text) > s * mu * m
